@@ -37,6 +37,8 @@ def _render(mod, rows) -> str:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--quick', action='store_true')
     ap.add_argument('--only', default='')

@@ -95,6 +95,12 @@ class LuminaConfig:
                            self.cache._replace(k=self.k_record))
 
 
+def platform_backend() -> str:
+    """The shade backend for this platform: the Pallas kernels where they
+    compile natively (TPU), the pure-JAX reference elsewhere."""
+    return 'pallas' if jax.default_backend() == 'tpu' else 'reference'
+
+
 class FrameStats(NamedTuple):
     hit_rate: jax.Array          # fraction of pixels served from the cache
     sig_frac: jax.Array          # significant / iterated Gaussians

@@ -13,8 +13,12 @@ Modes (mirroring the LuminCore execution phases):
                              ``repro.core.pipeline`` but with the compute
                              savings realized at chunk granularity.
 
-``interpret`` defaults to True off-TPU (CPU container); on TPU the kernels
-compile natively.
+``interpret=None`` resolves from the platform in one place
+(``default_interpret``): the Pallas interpreter off-TPU, native Mosaic
+kernels on TPU.  All four ``pallas_call``s compile for a TPU v5e at the
+paper configuration's widths (``tests/test_tpu_compile.py``), and the
+slot-batched serving path (phase A, lookup, compacted phase B) has run on
+one v5e chip through ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -32,14 +36,16 @@ from repro.kernels import rc_lookup as lk
 
 
 def default_interpret() -> bool:
+    """The one place ``interpret`` is resolved: native kernels on TPU, the
+    Pallas interpreter everywhere else."""
     return jax.default_backend() != 'tpu'
 
 
 def default_body(interpret: bool) -> str:
-    """Chunk-backend flavor: the scan+MXU 'dense' body is built for TPU
-    vector/matrix units; interpret mode (CPU) pays its log(C) scan passes
-    for real, so it gets the sequential FIFO body (which also skips
-    render-pose-invisible Gaussians with a real branch)."""
+    """Chunk-backend flavor: the scan 'dense' body is built for TPU vector
+    units; interpret mode (CPU) pays its log(C) scan passes for real, so it
+    gets the sequential FIFO body (which also skips render-pose-invisible
+    Gaussians with a real branch, and which Mosaic cannot lower)."""
     return 'seq' if interpret else 'dense'
 
 
@@ -292,10 +298,11 @@ def rc_lookup(cache: rc.CacheState, ids: jax.Array, cfg: rc.CacheConfig,
     if interpret:
         from repro.kernels import ref
         return ref.rc_lookup_ref(cache.tags, cache.values, ids, cfg)
+    # the largest lane-aligned divisor of B up to ``query_chunk`` (a query
+    # block must be a multiple of 128 lanes or all of B)
     b = ids.shape[1]
-    qc = min(query_chunk, b)
-    while b % qc:
-        qc -= 1
+    qc = next((q for q in range(min(query_chunk, b), 0, -1)
+               if b % q == 0 and q % rk.LANES == 0), b)
     return lk.rc_lookup_pallas(cache.tags, cache.values, ids, cfg,
                                query_chunk=qc, interpret=interpret)
 

@@ -1,6 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# A host-only compile tool: it and every child it starts stay on the CPU
+# (children inherit this environment), and the forced device count is
+# added to whatever XLA_FLAGS already holds.  MUST precede any jax import:
+# jax locks the platform and device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512"
+                           ).strip()
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the real step function (train_step for train

@@ -54,13 +54,14 @@ def make_serve_mesh(num_devices: int | None = None):
     return jax.sharding.Mesh(np.asarray(avail[:n]), (DEVICES_AXIS,))
 
 
-def serve_devices(num_workers: int) -> list:
+def serve_devices(num_workers: int, available=None) -> list:
     """Device handle per fleet worker, cycling over the available devices.
 
     Unlike a mesh, workers may OVERSUBSCRIBE: tier-1 CI runs the N-worker
     fleet on a single CPU device (workers are independent host loops over
     per-device steppers, not collective participants), while the
     multi-device CI job and real deployments get one worker per distinct
-    device."""
-    avail = jax.devices()
+    device.  ``available`` narrows the devices cycled over (default: all
+    of them) — e.g. one device, to oversubscribe every worker onto it."""
+    avail = list(available) if available is not None else jax.devices()
     return [avail[i % len(avail)] for i in range(num_workers)]

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data.tokens import synthetic_tokens
 from repro.models import registry
+from repro.runtime.compile_cache import use_compile_cache
 
 
 @dataclasses.dataclass
@@ -148,6 +149,7 @@ def run(arch: str, *, slots: int = 4, n_requests: int = 8,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', required=True)
     ap.add_argument('--slots', type=int, default=4)

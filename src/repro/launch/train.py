@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.data.tokens import TokenStream
 from repro.models import registry
 from repro.optim import adam, schedule
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.straggler import StragglerDetector
 
 
@@ -106,6 +107,7 @@ def _frames_for(cfg, tokens):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', required=True)
     ap.add_argument('--steps', type=int, default=100)

@@ -244,6 +244,18 @@ class FleetWorker:
     mgr: SessionManager
     ckpt: object = None
 
+    def on_device(self):
+        """Scope for every device action of this worker (tick, restore,
+        placement): arrays it creates land on its own device, not on the
+        process default (the first device)."""
+        return jax.default_device(self.device)
+
+    def state_devices(self) -> set:
+        """Devices holding this worker's stepper state (placement check)."""
+        st = self.mgr.stepper
+        return {d for x in jax.tree.leaves((st.shared, st.priv))
+                for d in x.devices()}
+
 
 class FleetManager:
     """Scene-sharded serving across N device workers (see module docs).
@@ -284,13 +296,15 @@ class FleetManager:
               slots_per_device: int, viewers_per_scene: int = 1,
               profile_every: int = 0, ckpt_root=None, ckpt_every: int = 0,
               max_pending: Optional[int] = None, injector=None,
-              tracer=None, metrics=None, stepper_cls=BatchedStepper):
-        """One worker per device (``launch.mesh.serve_devices`` — distinct
-        devices when available, oversubscribed on single-device CI).  Each
-        stepper is constructed under ``jax.default_device`` so its arrays
-        commit to its worker's device."""
+              tracer=None, metrics=None, stepper_cls=BatchedStepper,
+              devices=None):
+        """One worker per device (``launch.mesh.serve_devices`` over
+        ``devices``, default all of them — distinct devices when available,
+        oversubscribed on single-device CI).  Each stepper is constructed
+        under its worker's ``on_device`` scope so its arrays land on its
+        worker's device."""
         from repro.checkpoint.manager import CheckpointManager
-        devices = serve_devices(num_devices)
+        devices = serve_devices(num_devices, devices)
         workers = []
         for d, dev in enumerate(devices):
             with jax.default_device(dev):
@@ -340,8 +354,10 @@ class FleetManager:
         step = max(common)
         self.sessions = {s.sid: s for s in sessions}
         for w in self.workers:
-            if w.mgr.restore_serving(w.ckpt, sessions,
-                                     max_step=step) is None:
+            with w.on_device():
+                restored = w.mgr.restore_serving(w.ckpt, sessions,
+                                                 max_step=step)
+            if restored is None:
                 return None
         ticks = {w.mgr.tick for w in self.workers}
         if len(ticks) != 1:
@@ -462,7 +478,8 @@ class FleetManager:
         """One worker's tick leg: run, evict, and keep the stepper clock in
         lockstep (idle ticks advance ``global_tick`` too — the fleet-wide
         shared sort-cadence clock that slot-aligned moves rely on)."""
-        frames = w.mgr.run_tick()
+        with w.on_device():
+            frames = w.mgr.run_tick()
         stepper = w.mgr.stepper
         if getattr(stepper, 'global_tick', w.mgr.tick) < w.mgr.tick:
             stepper.global_tick = w.mgr.tick
@@ -472,7 +489,8 @@ class FleetManager:
     def _after_tick(self) -> None:
         self.tick += 1
         for w in self.alive_workers():
-            w.mgr.maybe_checkpoint()
+            with w.on_device():
+                w.mgr.maybe_checkpoint()
 
     def run_tick(self) -> int:
         """One synchronous fleet tick (the virtual N-device oracle leg)."""
@@ -528,8 +546,9 @@ class FleetManager:
         payload = sw.mgr.stepper.extract_viewer(slot, with_scene=aligned)
         sess = sw.mgr.vacate(slot)
         target = slot if aligned else free[0]
-        dw.mgr.place(target, sess, payload=payload,
-                     admitted_tick=sess.telemetry.admitted_tick)
+        with dw.on_device():
+            dw.mgr.place(target, sess, payload=payload,
+                         admitted_tick=sess.telemetry.admitted_tick)
         self.home[sid] = dst
         self.metrics.counter('fleet.migrations',
                              'viewer moves between devices',
@@ -603,7 +622,8 @@ class FleetManager:
         survivors = self.alive_workers()
         ticks = set()
         for w in survivors:
-            step = w.mgr.restore_serving(w.ckpt, all_sessions)
+            with w.on_device():
+                step = w.mgr.restore_serving(w.ckpt, all_sessions)
             if step is None:
                 raise RuntimeError(
                     f'device {w.device_id} has no usable checkpoint — '
@@ -660,8 +680,9 @@ class FleetManager:
             sess.telemetry.rollback(cursor)
             payload = viewer_payload_from_state(
                 arrays, meta['stepper'], slot, viewers_per_scene=vps)
-            self.workers[dev].mgr.place(slot, sess, payload=payload,
-                                        admitted_tick=adm)
+            with self.workers[dev].on_device():
+                self.workers[dev].mgr.place(slot, sess, payload=payload,
+                                            admitted_tick=adm)
             self.home[sid] = dev
             self.metrics.counter('fleet.migrations',
                                  'viewer moves between devices',

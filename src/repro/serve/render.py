@@ -20,9 +20,10 @@ import jax
 
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager
-from repro.core.pipeline import LuminaConfig
+from repro.core.pipeline import LuminaConfig, platform_backend
 from repro.data.scenes import structured_scene
 from repro.data.trajectory import orbit_trajectory
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve import faults as serve_faults
 from repro.serve import traffic
 from repro.serve.session import SessionManager, ViewerSession
@@ -31,6 +32,7 @@ from repro.serve.telemetry import aggregate, format_table, tick_rollup
 
 
 def build_sessions(viewers: int, frames: int, *, width: int = 96,
+                   height: int | None = None,
                    stagger: int = 2, fps: float = 90.0,
                    viewers_per_scene: int = 1,
                    arrivals=None, paces=None) -> list[ViewerSession]:
@@ -44,13 +46,15 @@ def build_sessions(viewers: int, frames: int, *, width: int = 96,
 
     ``arrivals``/``paces`` override the default ``sid * stagger`` arrival
     ticks and every-tick pacing — pass a ``repro.serve.traffic`` trace's
-    fields to serve an open-loop workload.
+    fields to serve an open-loop workload.  ``height`` defaults to
+    ``width`` (square frames).
     """
     sessions = []
     n_scenes = -(-viewers // viewers_per_scene)
     for sid in range(viewers):
         scene_id = sid // viewers_per_scene
-        cams = orbit_trajectory(frames, fps=fps, width=width, height_px=width,
+        cams = orbit_trajectory(frames, fps=fps, width=width,
+                                height_px=height or width,
                                 start_deg=360.0 * scene_id / max(n_scenes, 1))
         sessions.append(ViewerSession(
             sid=sid, cams=cams,
@@ -64,7 +68,7 @@ def build_sessions(viewers: int, frames: int, *, width: int = 96,
 def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
           gaussians: int = 1500, window: int = 6, capacity: int = 192,
           stagger: int = 2, sequential: bool = False, seed: int = 0,
-          backend: str = 'reference', profile_every: int = 0,
+          backend: str | None = None, profile_every: int = 0,
           viewers_per_scene: int = 1, arrivals: str = 'stagger',
           rate: float = 0.5, burst: int = 4, gap: int = 8, jitter: int = 0,
           pace: int = 1, pace_jitter: int = 0, oversubscribe: bool = False,
@@ -81,7 +85,8 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
           print_fn=print) -> dict:
     """Run the serving loop to completion; returns the aggregate rollup.
 
-    ``backend`` selects the shade implementation ('reference' | 'pallas');
+    ``backend`` selects the shade implementation ('reference' | 'pallas';
+    ``None`` = the platform's, ``platform_backend``: the kernels on TPU);
     ``profile_every`` > 0 samples a per-kernel shade latency breakdown every
     N ticks (pallas backend, batched engine); ``viewers_per_scene`` > 1
     groups that many slots per scene so co-scene viewers share one radiance
@@ -149,6 +154,7 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
         raise SystemExit('--stream is a single-device feature for now '
                          '(fleet workers hold fully-resident scene copies)')
     slots = slots or min(viewers, 8)
+    backend = backend or platform_backend()
     # scene blocks are static: round slots up to whole blocks
     slots = -(-slots // viewers_per_scene) * viewers_per_scene
     scene = structured_scene(jax.random.PRNGKey(seed), gaussians)
@@ -423,6 +429,7 @@ def _serve_fleet_path(scene, cfg, cam0, sessions, *, devices, slots, driver,
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument('--viewers', type=int, default=4)
     ap.add_argument('--frames', type=int, default=24)
@@ -438,9 +445,11 @@ def main(argv=None):
     ap.add_argument('--sequential', action='store_true',
                     help='per-slot stepping instead of one vmapped call')
     ap.add_argument('--backend', choices=('reference', 'pallas'),
-                    default='reference',
-                    help='shade implementation: pure-JAX reference or the '
-                         'chunked Pallas kernel path')
+                    default=None,
+                    help='shade implementation: pure-JAX reference (the '
+                         'oracle) or the chunked Pallas kernel path '
+                         '(default: the kernels on TPU, the reference '
+                         'elsewhere)')
     ap.add_argument('--profile-every', type=int, default=0,
                     help='sample a per-kernel shade latency breakdown every '
                          'N ticks (pallas backend, batched engine)')

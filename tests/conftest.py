@@ -1,121 +1,10 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 CPU device;
-only launch/dryrun.py forces 512 host devices (in its own process).
-
-Also installs a minimal ``hypothesis`` fallback when the real package is not
-available (the container ships without it): property tests still execute
-their examples as deterministic randomized checks (a regression still
-fails), but then report as SKIPPED — a shim pass is not real property
-coverage (no shrinking, no edge-case strategies, no database) and must not
-read as one.  CI installs the real package, so property tests pass or fail
-for real there.
-"""
-import random
-import sys
-import types
-
+only launch/dryrun.py forces 512 host devices (in its own process)."""
 import jax
-import jax.numpy as jnp
 import pytest
 
 from repro.data.scenes import structured_scene
 from repro.data.trajectory import orbit_trajectory
-
-
-def _install_hypothesis_shim():
-    """Register a tiny stand-in ``hypothesis`` module in sys.modules.
-
-    Supports exactly what this suite uses: ``@settings(max_examples=...,
-    deadline=...)``, ``@given(...)`` and the ``integers`` / ``floats`` /
-    ``lists`` / ``tuples`` / ``sampled_from`` strategies plus ``.map``.
-    Examples are drawn from a seeded RNG so runs are deterministic;
-    shrinking and the database are (deliberately) absent — which is why a
-    shim-backed test that survives its examples reports as skipped, not
-    passed (``pytest.skip`` after the example loop): the real strategies
-    only run where CI installs real hypothesis.
-    """
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rnd):
-            return self._draw(rnd)
-
-        def map(self, fn):
-            return _Strategy(lambda rnd: fn(self._draw(rnd)))
-
-    def integers(min_value, max_value):
-        return _Strategy(lambda rnd: rnd.randint(min_value, max_value))
-
-    def floats(min_value, max_value, **_kw):
-        return _Strategy(lambda rnd: rnd.uniform(min_value, max_value))
-
-    def sampled_from(options):
-        opts = list(options)
-        return _Strategy(lambda rnd: opts[rnd.randrange(len(opts))])
-
-    def tuples(*strats):
-        return _Strategy(lambda rnd: tuple(s.draw(rnd) for s in strats))
-
-    def lists(elements, *, min_size=0, max_size=10, unique=False):
-        def draw(rnd):
-            n = rnd.randint(min_size, max_size)
-            out = []
-            attempts = 0
-            while len(out) < n and attempts < 50 * (n + 1):
-                v = elements.draw(rnd)
-                attempts += 1
-                if unique and v in out:
-                    continue
-                out.append(v)
-            return out
-        return _Strategy(draw)
-
-    def settings(max_examples=20, deadline=None, **_kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(*strats):
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                # read from the wrapper: @settings sits OUTSIDE @given and
-                # sets the attribute on the object given returned
-                n = getattr(wrapper, '_shim_max_examples',
-                            getattr(fn, '_shim_max_examples', 20))
-                rnd = random.Random(f'{fn.__name__}:0')
-                for _ in range(n):
-                    fn(*args, *(s.draw(rnd) for s in strats), **kwargs)
-                # every example held, but only against the shim's naive
-                # uniform draws: report skipped, not (vacuously) passed
-                pytest.skip(f'hypothesis not installed: shim ran {n} '
-                            f'deterministic examples (all held); install '
-                            f'hypothesis for real property coverage')
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
-
-    mod = types.ModuleType('hypothesis')
-    mod.given = given
-    mod.settings = settings
-    mod.__is_repro_shim__ = True
-    strategies = types.ModuleType('hypothesis.strategies')
-    strategies.integers = integers
-    strategies.floats = floats
-    strategies.lists = lists
-    strategies.tuples = tuples
-    strategies.sampled_from = sampled_from
-    mod.strategies = strategies
-    sys.modules['hypothesis'] = mod
-    sys.modules['hypothesis.strategies'] = strategies
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_shim()
 
 
 @pytest.fixture(scope='session')

@@ -608,3 +608,53 @@ def test_degraded_fleet_sheds_new_load_not_accepted_viewers(fleet_steppers):
     agg = fm.aggregate()
     assert agg['devices'] == 2 and agg['alive_devices'] == 1
     assert agg['shed'] == 3
+
+
+# ---------------------------------------------------------------------------
+# Placement: every worker's state lives on its own device
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_CHILD = '''
+import json
+import jax
+from repro.core.pipeline import LuminaConfig
+from repro.data.scenes import structured_scene
+from repro.data.trajectory import orbit_trajectory
+from repro.serve.fleet import serve_fleet
+from repro.serve.session import ViewerSession
+
+scene = structured_scene(jax.random.PRNGKey(0), 300)
+cams = [orbit_trajectory(3, width=32, height_px=32, start_deg=90.0 * i)
+        for i in range(4)]
+sessions = [ViewerSession(sid=i, cams=c, scene_id=i)
+            for i, c in enumerate(cams)]
+fm, finished = serve_fleet(scene, LuminaConfig(capacity=64, window=2),
+                           cams[0][0], sessions, num_devices=4,
+                           slots_per_device=1)
+print(json.dumps({
+    'placement': [[str(w.device), sorted(map(str, w.state_devices()))]
+                  for w in fm.workers],
+    'frames': sorted(s.telemetry.frames for s in finished)}))
+'''
+
+
+def test_fleet_worker_state_stays_on_its_device():
+    """Ticks, admissions and restores run under their worker's device: with
+    four distinct (virtual CPU) devices, each worker's stepper state sits
+    on its own device after serving — nothing drifts to the first one."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               XLA_FLAGS=(os.environ.get('XLA_FLAGS', '')
+                          + ' --xla_force_host_platform_device_count=4'))
+    out = subprocess.run([sys.executable, '-c', _PLACEMENT_CHILD], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    devices = [dev for dev, _ in res['placement']]
+    assert len(set(devices)) == 4, devices
+    for dev, held in res['placement']:
+        assert held == [dev], (dev, held)
+    assert res['frames'] == [3, 3, 3, 3]
