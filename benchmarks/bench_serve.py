@@ -84,9 +84,6 @@ WIDTH = 64
 GAUSS = 1200
 CAPACITY = 192
 WINDOW = 4
-PROFILE_EVERY = 3   # per-kernel sampling cadence on pallas rows (odd, so
-                    # samples do not all land on sort-cohort ticks or, in
-                    # --quick runs, on the drained tail)
 # streaming row: arena budget in bytes (52 chunk frames of 64 gaussians).
 # Sized so the co-watching pair's ~44-chunk working set fits with prefetch
 # headroom (stalls stay 0) while the arena stays well below the 87-chunk
@@ -128,11 +125,9 @@ class _Cell:
         self.pool_size = pool_size
         self.sess_vps = vps if sess_vps is None else sess_vps
         cfg = LuminaConfig(capacity=CAPACITY, window=WINDOW, backend=backend)
-        profile = PROFILE_EVERY if backend == 'pallas' else 0
         cam0 = build_sessions(1, 1, width=WIDTH)[0].cams[0]
         if mode == 'sequential':
-            self.stepper = SequentialStepper(scene, cfg, cam0, self.slots,
-                                             profile_every=profile)
+            self.stepper = SequentialStepper(scene, cfg, cam0, self.slots)
         else:
             streaming = None
             if stream_budget:
@@ -144,7 +139,6 @@ class _Cell:
                                              lod_radius=5,
                                              budget_bytes=stream_budget)
             self.stepper = BatchedStepper(scene, cfg, cam0, self.slots,
-                                          profile_every=profile,
                                           viewers_per_scene=vps,
                                           pool_size=pool_size,
                                           streaming=streaming)
@@ -177,15 +171,12 @@ class _Cell:
         # absorbs every sort-on-admit burst); excluded from the timed run
         # and the per-tick sort accounting
         mgr.run_tick()
-        prof0 = self.stepper.profile_s
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             if injector.enabled:   # injected deaths warn by design
                 warnings.simplefilter('ignore', RuntimeWarning)
             finished = mgr.run(driver=self.driver)
-        # per-kernel profiling runs outside the serving work proper;
-        # subtract its overhead so fps compares backends, not cadences
-        wall = time.perf_counter() - t0 - (self.stepper.profile_s - prof0)
+        wall = time.perf_counter() - t0
         if injector.enabled:
             # faults degrade service, never drop it
             assert all(s.telemetry.frames == self.frames for s in finished), \
@@ -267,7 +258,6 @@ class _Cell:
             'max_sorts_per_tick': roll['max_sorts_per_tick'],
             'sort_ms': roll['mean_sort_ms'],
             'shade_ms': roll['mean_shade_ms'],
-            'kernel_ms': roll['kernel_ms'],
         }
         # uniform columns across engines (fmt_rows wants one schema); the
         # sequential baseline reports no occupancy scan (see its
@@ -393,7 +383,6 @@ class _FleetCell:
             'max_sorts_per_tick': roll['max_sorts_per_tick'],
             'sort_ms': roll['mean_sort_ms'],
             'shade_ms': roll['mean_shade_ms'],
-            'kernel_ms': roll['kernel_ms'],
         }
         for key in ('last_occupancy', 'max_sort_pool_live',
                     'sort_pool_bytes', 'sort_pool_alloc_bytes',

@@ -57,6 +57,7 @@ from repro.core.s2 import (SortShared, empty_sort_shared,
                            speculative_sort)
 from repro.core.sorting import sort_scene
 from repro.core.tiling import TILE, gather_tile_features, tile_grid
+from repro.obs.trace import shade_stage
 
 
 @dataclasses.dataclass(frozen=True)
@@ -644,11 +645,13 @@ def _batched_shade_pallas(scene: GaussianScene, shared: SceneShared,
     from repro.kernels import ops
     tiles_x, tiles_y = tile_grid(cams.width, cams.height)
     s = sorted_flags.shape[0]
-    feats_b = batched_prep_features(scene, shared, priv, cams, cfg,
-                                    viewers_per_scene)
-    feats_b = trim_features_slots(feats_b, tiles_x)
+    with shade_stage('prep'):
+        feats_b = batched_prep_features(scene, shared, priv, cams, cfg,
+                                        viewers_per_scene)
+        feats_b = trim_features_slots(feats_b, tiles_x)
 
     if cfg.use_rc:
+        # the kernel wrapper marks its raster, probe and insert stages
         colors, caches, aux, kst = ops.rasterize_with_rc_slots(
             feats_b, tiles_x, tiles_y, shared.cache, cfg.cache,
             cfg.group_tiles, viewers_per_scene=viewers_per_scene,
@@ -661,16 +664,18 @@ def _batched_shade_pallas(scene: GaussianScene, shared: SceneShared,
                        / jnp.maximum(kst.chunks_bound, 1))
         saved_b = jnp.broadcast_to(saved, (s,))
     else:
-        colors, aux, _ = ops.rasterize_full_slots(
-            feats_b, tiles_x, k_record=cfg.k_record, chunk=cfg.shade_chunk,
-            bg=cfg.bg, live=active)
+        with shade_stage('raster'):
+            colors, aux, _ = ops.rasterize_full_slots(
+                feats_b, tiles_x, k_record=cfg.k_record,
+                chunk=cfg.shade_chunk, bg=cfg.bg, live=active)
         caches = shared.cache
         hit = jnp.zeros(aux.n_iterated.shape, bool)
         saved_b = jnp.zeros((s,), jnp.float32)
 
-    images = jax.vmap(
-        lambda c: assemble_image(c, tiles_x, tiles_y, cams.width,
-                                 cams.height))(colors)
+    with shade_stage('raster'):
+        images = jax.vmap(
+            lambda c: assemble_image(c, tiles_x, tiles_y, cams.width,
+                                     cams.height))(colors)
     stats = jax.vmap(_stats)(aux, hit, saved_b, sorted_flags)
     new_shared = dataclasses.replace(shared, cache=caches)
     new_priv = dataclasses.replace(priv, prev_cam=cams,

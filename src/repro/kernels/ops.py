@@ -19,6 +19,11 @@ kernels on TPU.  All four ``pallas_call``s compile for a TPU v5e at the
 paper configuration's widths (``tests/test_tpu_compile.py``), and the
 slot-batched serving path (phase A, lookup, compacted phase B) has run on
 one v5e chip through ``chip_smoke.py``.
+
+In a device trace each kernel's operation carries the ``name=`` of its
+``pallas_call``: ``_kernel_slots`` (phase A, slot-batched),
+``_kernel_compact`` (compacted phase B), ``rc_lookup`` (the probe) and
+``_kernel_tiles`` (the per-tile kernel of the unbatched wrappers).
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ from repro.core.rasterize import RasterAux, chunk_caps, pad_tile_features
 from repro.core.tiling import TileFeatures
 from repro.kernels import rasterize as rk
 from repro.kernels import rc_lookup as lk
+from repro.obs.trace import shade_stage
 
 
 def default_interpret() -> bool:
@@ -527,78 +533,86 @@ def rasterize_with_rc_slots(feats_b: TileFeatures, tiles_x: int,
                             interpret: bool | None = None):
     """Slot-batched cached rasterization: phase A in one slot-batched
     kernel, scene-major shared-cache probe, cross-slot miss-compacted
-    resume, scene-major insert.  ``caches`` leaves carry a leading [C] axis
-    with ``C = S // viewers_per_scene`` (slot ``i`` probes scene ``i // V``'s
-    cache; slots of one scene share it, conflicts resolving in deterministic
-    (slot, pixel) order — see ``rc_probe_multi``); ``live`` is [S] bool and
-    masks idle slots out of LRU touches and inserts as well as the chunk
-    loops.  With ``viewers_per_scene == 1`` every slot owns a private cache
+    resume, scene-major insert, each inside its shade stage's named scope
+    (``repro.obs.trace.shade_stage``: raster, rc_probe, rc_insert).
+    ``caches`` leaves carry a leading [C] axis with ``C = S //
+    viewers_per_scene`` (slot ``i`` probes scene ``i // V``'s cache; slots
+    of one scene share it, conflicts resolving in deterministic (slot,
+    pixel) order — see ``rc_probe_multi``); ``live`` is [S] bool and masks
+    idle slots out of LRU touches and inserts as well as the chunk loops.  With ``viewers_per_scene == 1`` every slot owns a private cache
     and per-lane results are bit-identical to mapping ``rasterize_with_rc``
     over slots; only the *chunk accounting* differs (phase-A trips are
     slot-coupled, so ``chunks_prefix``/``chunks_bound`` are fleet totals and
     ``hit_rate`` is per-slot [S]).
     """
-    feats_b = pad_features_slots(feats_b, chunk)
-    s, t = feats_b.ids.shape[:2]
-    v = viewers_per_scene
-    c = s // v
-    if live is None:
-        live = jnp.ones((s,), bool)
-    live = jnp.asarray(live, bool).reshape(s)
+    with shade_stage('raster'):
+        feats_b = pad_features_slots(feats_b, chunk)
+        s, t = feats_b.ids.shape[:2]
+        v = viewers_per_scene
+        c = s // v
+        if live is None:
+            live = jnp.ones((s,), bool)
+        live = jnp.asarray(live, bool).reshape(s)
 
-    st_a = rasterize_prefix_slots(feats_b, tiles_x, k_record=k_record,
-                                  chunk=chunk, live=live,
-                                  interpret=interpret)
+        st_a = rasterize_prefix_slots(feats_b, tiles_x, k_record=k_record,
+                                      chunk=chunk, live=live,
+                                      interpret=interpret)
 
-    ids_g = jax.vmap(
-        lambda r: regroup(r, tiles_x, tiles_y, group_tiles))(st_a.record)
-    ids_cv = ids_g.reshape(c, v, *ids_g.shape[1:])       # [C, V, G, B, k]
-    live_cv = live.reshape(c, v)
-    hit_cv, val_cv, way_cv, caches = jax.vmap(
-        lambda cc, ii, lv: rc_probe_multi(cc, ii, cfg, live=lv,
-                                          interpret=interpret)
-    )(caches, ids_cv, live_cv)
-    hit_g = hit_cv.reshape(s, *hit_cv.shape[2:])         # [S, G, B]
-    val_g = val_cv.reshape(s, *val_cv.shape[2:])
-    hit = jax.vmap(
-        lambda h: ungroup(h[..., None], tiles_x, tiles_y,
-                          group_tiles)[..., 0])(hit_g)
-    cached = jax.vmap(
-        lambda vv: ungroup(vv, tiles_x, tiles_y, group_tiles))(val_g)
+    with shade_stage('rc_probe'):
+        ids_g = jax.vmap(
+            lambda r: regroup(r, tiles_x, tiles_y, group_tiles))(st_a.record)
+        ids_cv = ids_g.reshape(c, v, *ids_g.shape[1:])   # [C, V, G, B, k]
+        live_cv = live.reshape(c, v)
+        hit_cv, val_cv, way_cv, caches = jax.vmap(
+            lambda cc, ii, lv: rc_probe_multi(cc, ii, cfg, live=lv,
+                                              interpret=interpret)
+        )(caches, ids_cv, live_cv)
+        hit_g = hit_cv.reshape(s, *hit_cv.shape[2:])     # [S, G, B]
+        val_g = val_cv.reshape(s, *val_cv.shape[2:])
+        hit = jax.vmap(
+            lambda h: ungroup(h[..., None], tiles_x, tiles_y,
+                              group_tiles)[..., 0])(hit_g)
+        cached = jax.vmap(
+            lambda vv: ungroup(vv, tiles_x, tiles_y, group_tiles))(val_g)
 
-    miss = ~hit & live[:, None, None]
-    if compact:
-        colors, aux, chunks_b = rasterize_resume_compacted_slots(
-            feats_b, tiles_x, st_a, miss, t_img=t, k_record=k_record,
-            chunk=chunk, bg=bg, interpret=interpret)
-    else:
-        colors, aux, chunks_b = jax.vmap(
-            lambda f, st, m: rasterize_resume(
-                TileFeatures(*f), tiles_x,
-                rk.RasterState(*st, chunks=jnp.zeros((t, 1), jnp.int32)), m,
-                k_record=k_record, chunk=chunk, bg=bg, interpret=interpret)
-        )(tuple(feats_b),
-          (st_a.acc, st_a.trans, st_a.record, st_a.rec_cnt, st_a.n_sig,
-           st_a.n_iter, st_a.iter_at_k), miss)
-    final = jnp.where(hit[..., None], cached, colors)
+    with shade_stage('raster'):
+        miss = ~hit & live[:, None, None]
+        if compact:
+            colors, aux, chunks_b = rasterize_resume_compacted_slots(
+                feats_b, tiles_x, st_a, miss, t_img=t, k_record=k_record,
+                chunk=chunk, bg=bg, interpret=interpret)
+        else:
+            colors, aux, chunks_b = jax.vmap(
+                lambda f, st, m: rasterize_resume(
+                    TileFeatures(*f), tiles_x,
+                    rk.RasterState(*st, chunks=jnp.zeros((t, 1), jnp.int32)),
+                    m, k_record=k_record, chunk=chunk, bg=bg,
+                    interpret=interpret)
+            )(tuple(feats_b),
+              (st_a.acc, st_a.trans, st_a.record, st_a.rec_cnt, st_a.n_sig,
+               st_a.n_iter, st_a.iter_at_k), miss)
+        final = jnp.where(hit[..., None], cached, colors)
 
-    raw_g = jax.vmap(
-        lambda cl: regroup(cl, tiles_x, tiles_y, group_tiles))(colors)
-    raw_cv = raw_g.reshape(c, v, *raw_g.shape[1:])
-    caches = jax.vmap(
-        lambda cc, ii, rr, dd: rc.insert_all_groups_multi(cc, ii, rr, dd, cfg)
-    )(caches, ids_cv, raw_cv, ~hit_cv & live_cv[:, :, None, None])
+    with shade_stage('rc_insert'):
+        raw_g = jax.vmap(
+            lambda cl: regroup(cl, tiles_x, tiles_y, group_tiles))(colors)
+        raw_cv = raw_g.reshape(c, v, *raw_g.shape[1:])
+        caches = jax.vmap(
+            lambda cc, ii, rr, dd: rc.insert_all_groups_multi(cc, ii, rr, dd,
+                                                              cfg)
+        )(caches, ids_cv, raw_cv, ~hit_cv & live_cv[:, :, None, None])
 
-    ncap = chunk_caps(feats_b.ids.reshape(s * t, -1), chunk)
-    stats = RCStats(
-        hit_rate=jnp.mean(hit.astype(jnp.float32), axis=(1, 2)),   # [S]
-        # one slot-coupled trip covers all S slots' lanes of its tile, so
-        # scale by S to keep the RCStats contract (chunks_prefix +
-        # chunks_resume comparable to chunks_bound, both in per-slot-tile
-        # chunk units)
-        chunks_prefix=jnp.sum(st_a.chunks) * s,   # fleet
-        chunks_resume=jnp.sum(chunks_b),          # fleet (cross-slot packed)
-        chunks_bound=jnp.sum(ncap),               # fleet
-        hit=hit,                                  # [S, T, P]
-    )
+    with shade_stage('raster'):
+        ncap = chunk_caps(feats_b.ids.reshape(s * t, -1), chunk)
+        stats = RCStats(
+            hit_rate=jnp.mean(hit.astype(jnp.float32), axis=(1, 2)),   # [S]
+            # one slot-coupled trip covers all S slots' lanes of its tile,
+            # so scale by S to keep the RCStats contract (chunks_prefix +
+            # chunks_resume comparable to chunks_bound, both in
+            # per-slot-tile chunk units)
+            chunks_prefix=jnp.sum(st_a.chunks) * s,   # fleet
+            chunks_resume=jnp.sum(chunks_b),          # fleet (cross-slot)
+            chunks_bound=jnp.sum(ncap),               # fleet
+            hit=hit,                                  # [S, T, P]
+        )
     return final, caches, aux, stats

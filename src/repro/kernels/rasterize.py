@@ -446,7 +446,7 @@ def rasterize_pallas(mean2d, conic, color, opacity, ids,
         out_shape=(*_state_shapes((t,), k_record),
                    jax.ShapeDtypeStruct((t, 1, 1), jnp.int32)),
         scratch_shapes=[pltpu.VMEM((_window(chunk), NF), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name='_kernel_tiles',
     )(ncap.reshape(t, 1, 1).astype(jnp.int32), plane,
       *_state_in(acc0, trans0, rec0, cnt0, start_iter, live))
     return _state_out(outs, chunks.reshape(t, 1))
@@ -587,7 +587,7 @@ def rasterize_compact_pallas(mean2d, conic, color, opacity, ids,
         scratch_shapes=[pltpu.VMEM((NF, _window(chunk)), jnp.float32),
                         pltpu.VMEM((_window(chunk), NF), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
-        interpret=interpret,
+        interpret=interpret, name='_kernel_compact',
     )(row(srcs), nsrc.reshape(ct, 1, 1), plane,
       row(px.astype(jnp.float32)), row(py.astype(jnp.float32)), row(src),
       row(ncap.astype(jnp.int32)),
@@ -704,7 +704,7 @@ def rasterize_slots_pallas(mean2d, conic, color, opacity, ids,
         out_shape=(*_state_shapes((s, t), k_record),
                    jax.ShapeDtypeStruct((t, 1, 1), jnp.int32)),
         scratch_shapes=[pltpu.VMEM((_window(chunk), NF), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name='_kernel_slots',
     )(ncap.astype(jnp.int32).T.reshape(t, 1, s), plane,
       *_state_in(acc0, trans0, rec0, cnt0, start_iter, live))
     return _state_out(outs, chunks.reshape(t, 1))

@@ -142,7 +142,7 @@ def rc_lookup_pallas(tags: jax.Array, values: jax.Array, ids: jax.Array,
             jax.ShapeDtypeStruct((g, 1, b), jnp.int32),
             jax.ShapeDtypeStruct((g, 1, b), jnp.int32),
         ),
-        interpret=interpret,
+        interpret=interpret, name='rc_lookup',
     )(tags.reshape(g, s, w * k), values.reshape(g, s, w * 3),
       jnp.swapaxes(ids, 1, 2))
     return (hit[:, 0] != 0, jnp.swapaxes(val, 1, 2), sidx[:, 0], way[:, 0])

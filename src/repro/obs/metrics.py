@@ -223,7 +223,6 @@ def _jsonable(obj):
 # -- the tick-series mirror of SessionManager.tick_log ----------------------
 
 TICK_PREFIX = 'tick.'
-_KERNEL_PREFIX = TICK_PREFIX + 'kernel_ms.'
 
 
 def publish_tick(registry: Registry, entry: dict) -> None:
@@ -231,19 +230,11 @@ def publish_tick(registry: Registry, entry: dict) -> None:
 
     Scalar fields land verbatim on ``tick.<field>`` (values are stored
     as-is — possibly still-unsynced device scalars, exactly like the dict
-    path; ``tick_rollup`` is where they become floats), the nested
-    ``kernel_ms`` breakdown on ``tick.kernel_ms.<stage>``.  A ``None``
-    ``kernel_ms`` (unprofiled tick) records nothing, matching the dict
-    path's falsy-skip.
+    path; ``tick_rollup`` is where they become floats).
     """
     tick = entry['tick']
     for key, value in entry.items():
         if key == 'tick':
-            continue
-        if key == 'kernel_ms':
-            if value:
-                for stage, ms in value.items():
-                    registry.series(_KERNEL_PREFIX + stage).record(tick, ms)
             continue
         registry.series(TICK_PREFIX + key).record(tick, value)
 
@@ -252,11 +243,8 @@ def tick_log_from_registry(registry: Registry) -> list:
     """Reconstruct the tick log from the registry's ``tick.*`` series —
     the inverse of :func:`publish_tick`, up to dict key order."""
     fields: dict[str, dict] = {}
-    kernel: dict[str, dict] = {}
     for key in registry.names():
-        if key.startswith(_KERNEL_PREFIX):
-            kernel[key[len(_KERNEL_PREFIX):]] = dict(registry[key].samples)
-        elif key.startswith(TICK_PREFIX):
+        if key.startswith(TICK_PREFIX):
             fields[key[len(TICK_PREFIX):]] = dict(registry[key].samples)
     ticks = sorted({t for by_tick in fields.values() for t in by_tick})
     log = []
@@ -265,9 +253,6 @@ def tick_log_from_registry(registry: Registry) -> list:
         for field, by_tick in fields.items():
             if t in by_tick:
                 entry[field] = by_tick[t]
-        kms = {stage: by_tick[t] for stage, by_tick in kernel.items()
-               if t in by_tick}
-        entry['kernel_ms'] = kms or None
         log.append(entry)
     return log
 

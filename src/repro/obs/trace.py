@@ -13,7 +13,7 @@ thread lane, see ``repro.obs.export``):
     track='device')`` for intervals whose begin/end straddle calls, e.g.
     the device window of an async shade (``step_dispatch`` records the
     dispatch time, ``step_finish`` closes the span once
-    ``block_until_ready`` returns) and the sampled kernel-stage breakdown;
+    ``block_until_ready`` returns);
   * **instants** — ``tracer.instant('admit', slot=3, sid=7)`` for traffic
     events (arrival / admit / evict / pace) that have no duration.
 
@@ -23,18 +23,46 @@ of the recorded spans — per-track (name, depth, args) sequences, exposed by
 :func:`span_structure` — is bit-identical across replays.  Timestamps are
 wall-clock and of course differ; they never enter the structure.
 
+On the profiler's clock: a live tracer also opens each context-manager
+span as ``jax.profiler.TraceAnnotation('lumina.<name>')`` on the calling
+thread, so whenever a ``jax.profiler`` session is open the host spans sit
+in its trace beside the device's operations.  The device program marks
+its own stages instead: :func:`shade_stage` wraps each stage of the shade
+step in a ``jax.named_scope``, so every operation of the stage carries
+``shade/<stage>`` in its HLO metadata (the trace's ``tf_op``).
+
 Overhead: the module-level :data:`NULL` tracer is the default everywhere —
 its ``span`` returns one shared no-op context manager and ``complete`` /
 ``instant`` are empty methods, so uninstrumented serving pays a single
 attribute lookup per site.  A live tracer appends one small tuple per
 event under a lock (the threaded driver's planner worker and the main
-thread both record).
+thread both record), and enters one profiler annotation per span (a no-op
+while no profiler session is open).
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Callable, NamedTuple, Optional
+
+import jax
+
+#: Stages of the shade step, each a ``jax.named_scope('shade/<stage>')``:
+#: ``prep`` the S^2 feature refresh and trim, ``raster`` the kernels'
+#: phases A and B with the miss compaction and image assembly, ``rc_probe``
+#: and ``rc_insert`` the radiance-cache probe and insert with their
+#: regrouping, ``lanes`` the stepper's lane-compaction gathers and scatters.
+SHADE_STAGES = ('prep', 'raster', 'rc_probe', 'rc_insert', 'lanes')
+
+#: Prefix of the tracer's spans in a ``jax.profiler`` trace.
+PROFILER_PREFIX = 'lumina.'
+
+
+def shade_stage(name: str):
+    """The named scope of one stage of the shade step (``SHADE_STAGES``)."""
+    if name not in SHADE_STAGES:
+        raise ValueError(f'unknown shade stage {name!r}')
+    return jax.named_scope(f'shade/{name}')
 
 # Event phases, mirroring the Chrome trace-event vocabulary the exporter
 # targets: 'X' = complete span (ts + dur), 'i' = instant.
@@ -70,7 +98,7 @@ class TraceEvent(NamedTuple):
 class _Span:
     """Reusable enter/exit handle for one context-manager span."""
 
-    __slots__ = ('_tracer', '_name', '_track', '_args', '_t0')
+    __slots__ = ('_tracer', '_name', '_track', '_args', '_t0', '_ann')
 
     def __init__(self, tracer: 'Tracer', name: str, track: str, args: tuple):
         self._tracer = tracer
@@ -81,12 +109,15 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         tr._push(self._track)
+        self._ann = jax.profiler.TraceAnnotation(PROFILER_PREFIX + self._name)
+        self._ann.__enter__()
         self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
         t1 = tr._clock()
+        self._ann.__exit__(*exc)
         depth = tr._pop(self._track)
         tr._record(TraceEvent(PH_SPAN, self._name, self._track,
                               self._t0, t1 - self._t0, depth, self._args))
@@ -141,7 +172,8 @@ class Tracer:
     def complete(self, name: str, t0: float, t1: float,
                  track: str = TRACK_DEVICE, depth: int = 0, **args) -> None:
         """Record a span whose begin/end were measured explicitly (seconds
-        on this tracer's clock) — device windows, sampled kernel stages."""
+        on this tracer's clock) — device windows.  Not written to the
+        profiler, whose trace has the device's own events."""
         self._record(TraceEvent(PH_SPAN, name, track, t0, max(0.0, t1 - t0),
                                 depth, tuple(sorted(args.items()))))
 

@@ -294,7 +294,7 @@ class FleetManager:
     @classmethod
     def build(cls, scene, cfg, cam0, *, num_devices: int,
               slots_per_device: int, viewers_per_scene: int = 1,
-              profile_every: int = 0, ckpt_root=None, ckpt_every: int = 0,
+              ckpt_root=None, ckpt_every: int = 0,
               max_pending: Optional[int] = None, injector=None,
               tracer=None, metrics=None, stepper_cls=BatchedStepper,
               devices=None):
@@ -310,7 +310,6 @@ class FleetManager:
             with jax.default_device(dev):
                 stepper = stepper_cls(
                     scene, cfg, cam0, slots_per_device,
-                    profile_every=profile_every,
                     viewers_per_scene=viewers_per_scene)
             mgr = SessionManager(stepper, slots_per_device,
                                  metrics=obs_metrics.Registry())
@@ -968,7 +967,7 @@ def get_fleet_driver(name: str, fleet: FleetManager, **kw):
 
 def serve_fleet(scene, cfg, cam0, sessions, *, num_devices: int,
                 slots_per_device: int, driver: str = 'sync',
-                viewers_per_scene: int = 1, profile_every: int = 0,
+                viewers_per_scene: int = 1,
                 ckpt_root=None, ckpt_every: int = 0, restore: bool = False,
                 max_pending: Optional[int] = None, injector=None,
                 tracer=None, max_ticks: int = 100_000,
@@ -987,7 +986,7 @@ def serve_fleet(scene, cfg, cam0, sessions, *, num_devices: int,
     fleet = FleetManager.build(
         scene, cfg, cam0, num_devices=num_devices,
         slots_per_device=slots_per_device,
-        viewers_per_scene=viewers_per_scene, profile_every=profile_every,
+        viewers_per_scene=viewers_per_scene,
         ckpt_root=ckpt_root, ckpt_every=ckpt_every,
         max_pending=max_pending, injector=injector, tracer=tracer)
     fleet.restored_tick = None

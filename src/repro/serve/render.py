@@ -15,6 +15,7 @@ plus sort-on-admit) — see repro.serve.stepper for the cadence-shift caveat.
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 
@@ -68,7 +69,7 @@ def build_sessions(viewers: int, frames: int, *, width: int = 96,
 def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
           gaussians: int = 1500, window: int = 6, capacity: int = 192,
           stagger: int = 2, sequential: bool = False, seed: int = 0,
-          backend: str | None = None, profile_every: int = 0,
+          backend: str | None = None, profile_dir: str | None = None,
           viewers_per_scene: int = 1, arrivals: str = 'stagger',
           rate: float = 0.5, burst: int = 4, gap: int = 8, jitter: int = 0,
           pace: int = 1, pace_jitter: int = 0, oversubscribe: bool = False,
@@ -87,8 +88,10 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
 
     ``backend`` selects the shade implementation ('reference' | 'pallas';
     ``None`` = the platform's, ``platform_backend``: the kernels on TPU);
-    ``profile_every`` > 0 samples a per-kernel shade latency breakdown every
-    N ticks (pallas backend, batched engine); ``viewers_per_scene`` > 1
+    ``profile_dir`` records a ``jax.profiler`` trace of the serving loop
+    there, with the span tracer live so its spans sit in the trace as
+    ``lumina.<span>`` beside the device operations, whose op names carry
+    the shade program's ``shade/<stage>`` scopes; ``viewers_per_scene`` > 1
     groups that many slots per scene so co-scene viewers share one radiance
     cache and pose-cell sort pool (batched engine only).  ``arrivals``
     selects the traffic trace ('stagger' | 'poisson' | 'bursty', seeded by
@@ -189,7 +192,7 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
         return _serve_fleet_path(
             scene, cfg, cam0, sessions, devices=devices, slots=slots,
             driver=driver, viewers_per_scene=viewers_per_scene,
-            profile_every=profile_every, injector=injector,
+            profile_dir=profile_dir, injector=injector,
             fault_trace=fault_trace, fault_rate=fault_rate,
             fault_seed=fault_seed, max_pending=max_pending,
             checkpoint_dir=checkpoint_dir,
@@ -198,8 +201,7 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
             metrics_out=metrics_out, print_fn=print_fn)
 
     if sequential:
-        stepper = SequentialStepper(scene, cfg, cam0, slots,
-                                    profile_every=profile_every)
+        stepper = SequentialStepper(scene, cfg, cam0, slots)
     else:
         streaming = None
         if stream:
@@ -213,11 +215,10 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
                 budget_bytes=stream_budget or None,
                 max_loads_per_tick=stream_max_loads or None)
         stepper = BatchedStepper(scene, cfg, cam0, slots,
-                                 profile_every=profile_every,
                                  viewers_per_scene=viewers_per_scene,
                                  streaming=streaming)
 
-    tracer = obs.Tracer() if trace_out else None
+    tracer = obs.Tracer() if trace_out or profile_dir else None
     mgr = SessionManager(stepper, slots, tracer=tracer, injector=injector,
                          watchdog_s=watchdog, max_pending=max_pending,
                          oversubscribe=oversubscribe)
@@ -237,7 +238,8 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
     if restored is None:
         for sess in sessions:
             mgr.submit(sess)
-    finished = mgr.run(driver=driver)
+    with _profiling(profile_dir, print_fn):
+        finished = mgr.run(driver=driver)
     if ckpt is not None:
         ckpt.wait()   # flush any in-flight background save
     if injector.enabled:
@@ -280,7 +282,6 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
     agg['max_sorts_per_tick'] = roll['max_sorts_per_tick']
     agg['tick_sort_ms'] = roll['mean_sort_ms']
     agg['tick_shade_ms'] = roll['mean_shade_ms']
-    agg['kernel_ms'] = roll['kernel_ms']
     for key in ('last_occupancy', 'max_sort_pool_live', 'sort_pool_bytes',
                 'sort_pool_alloc_bytes', 'sort_pool_reserved_bytes',
                 'cache_bytes', 'state_bytes', 'state_alloc_bytes',
@@ -324,9 +325,6 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
                  f"{agg['stream_evictions']} evictions, "
                  f"{agg['stream_stalls']} stalls "
                  f"({agg.get('stream_stalls_tail', 0)} post-warmup)")
-    if roll['kernel_ms']:
-        parts = '  '.join(f'{k} {v:.1f}' for k, v in roll['kernel_ms'].items())
-        print_fn(f"-- shade kernels (ms/tick, sampled): {parts}")
     if 'host_ms' in agg:
         print_fn(f"-- host pipeline ({driver}, {arrivals} arrivals): "
                  f"plan {agg['host_ms']:.2f} ms/tick, "
@@ -351,8 +349,32 @@ def serve(viewers: int, frames: int, *, slots: int = 0, width: int = 96,
     return agg
 
 
+@contextlib.contextmanager
+def _profiling(profile_dir: str | None, print_fn):
+    """A ``jax.profiler`` trace of the enclosed serving loop into
+    ``profile_dir``; nothing when it is None.
+
+    The persistent compile cache keys programs without their metadata by
+    default, so it would serve a program compiled before its scopes were
+    renamed with the old names; while profiling, the metadata is part of
+    the key."""
+    if not profile_dir:
+        yield
+        return
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
+    try:
+        with jax.profiler.trace(profile_dir):
+            yield
+    finally:
+        jax.config.update('jax_compilation_cache_include_metadata_in_key',
+                          keyed)
+    print_fn(f'-- profile: jax.profiler trace -> {profile_dir} '
+             f'(lumina.* host spans, shade/<stage> device scopes)')
+
+
 def _serve_fleet_path(scene, cfg, cam0, sessions, *, devices, slots, driver,
-                      viewers_per_scene, profile_every, injector,
+                      viewers_per_scene, profile_dir, injector,
                       fault_trace, fault_rate, fault_seed, max_pending,
                       checkpoint_dir, checkpoint_every, restore, backend,
                       arrivals, trace_out, metrics_out, print_fn) -> dict:
@@ -362,14 +384,15 @@ def _serve_fleet_path(scene, cfg, cam0, sessions, *, devices, slots, driver,
     ``checkpoint_dir`` (fail-fast ``SystemExit`` when absent — see
     ``serve_fleet``)."""
     from repro.serve.fleet import serve_fleet
-    tracer = obs.Tracer() if trace_out else None
-    fleet, finished = serve_fleet(
-        scene, cfg, cam0, sessions, num_devices=devices,
-        slots_per_device=slots, driver=driver,
-        viewers_per_scene=viewers_per_scene, profile_every=profile_every,
-        ckpt_root=checkpoint_dir, ckpt_every=checkpoint_every,
-        restore=restore, max_pending=max_pending,
-        injector=injector, tracer=tracer)
+    tracer = obs.Tracer() if trace_out or profile_dir else None
+    with _profiling(profile_dir, print_fn):
+        fleet, finished = serve_fleet(
+            scene, cfg, cam0, sessions, num_devices=devices,
+            slots_per_device=slots, driver=driver,
+            viewers_per_scene=viewers_per_scene,
+            ckpt_root=checkpoint_dir, ckpt_every=checkpoint_every,
+            restore=restore, max_pending=max_pending,
+            injector=injector, tracer=tracer)
     if fleet.restored_tick is not None:
         print_fn(f'-- restored serving state from tick '
                  f'{fleet.restored_tick} ({checkpoint_dir}, '
@@ -450,9 +473,10 @@ def main(argv=None):
                          'oracle) or the chunked Pallas kernel path '
                          '(default: the kernels on TPU, the reference '
                          'elsewhere)')
-    ap.add_argument('--profile-every', type=int, default=0,
-                    help='sample a per-kernel shade latency breakdown every '
-                         'N ticks (pallas backend, batched engine)')
+    ap.add_argument('--profile-dir', default=None, metavar='DIR',
+                    help='record a jax.profiler trace of the serving loop '
+                         'in DIR: lumina.* host spans and the shade '
+                         "program's shade/<stage> scopes")
     ap.add_argument('--viewers-per-scene', type=int, default=1,
                     help='slots per scene block: viewers of one scene share '
                          'its radiance cache and pose-cell sort pool '
@@ -548,7 +572,7 @@ def main(argv=None):
           gaussians=args.gaussians, window=args.window,
           capacity=args.capacity, stagger=args.stagger,
           sequential=args.sequential, seed=args.seed,
-          backend=args.backend, profile_every=args.profile_every,
+          backend=args.backend, profile_dir=args.profile_dir,
           viewers_per_scene=args.viewers_per_scene,
           arrivals=args.arrivals, rate=args.rate, burst=args.burst,
           gap=args.gap, jitter=args.jitter, pace=args.pace,

@@ -129,7 +129,7 @@ class SessionManager:
         self.slots = slots
         # Observability (repro.obs): a span tracer (NULL no-op by default)
         # and a typed metrics registry, shared with the stepper so sort
-        # scheduling / kernel-stage events land in the same trace.
+        # scheduling events land in the same trace.
         self.tracer = tracer if tracer is not None else obs_trace.NULL
         self.metrics = metrics if metrics is not None else \
             obs_metrics.Registry()
@@ -177,9 +177,8 @@ class SessionManager:
         self._carry_host_ms = 0.0
         self._carry_overlap_ms = 0.0
         # Per-tick phase attribution: {'tick', 'frames', 'sorted_slots',
-        # 'sort_ms', 'shade_ms', 'latency_ms', 'host_ms', 'overlap_ms',
-        # 'kernel_ms'} per rendered tick (empty ticks are skipped; kernel_ms
-        # is None except on profiled pallas ticks), plus the stepper's state
+        # 'sort_ms', 'shade_ms', 'latency_ms', 'host_ms', 'overlap_ms'} per
+        # rendered tick (empty ticks are skipped), plus the stepper's state
         # metrics (cache occupancy, live sort-pool entries, state bytes)
         # when it exposes ``state_metrics()``.
         self.tick_log: list[dict] = []
@@ -587,15 +586,20 @@ class SessionManager:
         series), and the clock advance to ``plan.tick + 1``."""
         with self.tracer.span('observe_tick', tick=plan.tick,
                               frames=len(outputs)), self._lock:
-            for slot, (_image, stats, timing) in outputs.items():
+            # the frames' counters, read from the device
+            with self.tracer.span('fetch', tick=plan.tick):
+                fetched = {slot: (float(stats.hit_rate),
+                                  float(stats.saved_frac),
+                                  float(stats.sorted_this_frame))
+                           for slot, (_image, stats, _t) in outputs.items()}
+            for slot, (_image, _stats, timing) in outputs.items():
                 sess = self.slot_session[slot]
-                hit_rate = float(stats.hit_rate)
-                saved_frac = float(stats.saved_frac)
+                hit_rate, saved_frac, sorted_flag = fetched[slot]
                 sess.telemetry.observe_frame(
                     latency_s=timing.latency_s,
                     hit_rate=hit_rate,
                     saved_frac=saved_frac,
-                    sorted_flag=float(stats.sorted_this_frame),
+                    sorted_flag=sorted_flag,
                     sort_ms=timing.sort_ms,
                     shade_ms=timing.shade_ms)
                 sess.cursor += 1
@@ -634,7 +638,6 @@ class SessionManager:
                                + (host.host_ms if host else 0.0),
                     'overlap_ms': self._carry_overlap_ms
                                   + (host.overlap_ms if host else 0.0),
-                    'kernel_ms': getattr(tick_timing, 'kernel_ms', None),
                 }
                 self._carry_host_ms = self._carry_overlap_ms = 0.0
                 metrics = getattr(self.stepper, 'state_metrics', None)

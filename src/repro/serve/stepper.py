@@ -49,13 +49,11 @@ scene ride the shade with ``active=False``: they contribute nothing, touch
 no LRU state and insert nothing into the shared cache.  With one slot per
 scene this reduces exactly to the PR-3 per-slot compaction.
 
-**Per-kernel latency attribution**: with ``profile_every=N`` (and the
-``pallas`` backend), every Nth tick re-runs the shade decomposed into its
-kernel stages — prep (S^2 feature refresh), prefix (RC phase A), lookup
-(scene-major LuminCache probe), resume (miss-compacted phase B), insert —
-on a copy of the pre-shade state, timing each stage with a device sync.
-The breakdown lands in ``TickTiming.kernel_ms`` / ``SessionManager.
-tick_log`` and is rolled up by ``telemetry.tick_rollup``.
+**Stage attribution**: the shade program marks its stages with
+``jax.named_scope`` (``repro.obs.trace.shade_stage``: prep, raster,
+rc_probe, rc_insert, and lanes for the lane-compaction gathers and
+scatters here), so a ``jax.profiler`` trace attributes every device
+operation of the one fused program to its stage.
 
 Interface::
 
@@ -94,14 +92,12 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.core.camera import Camera, stack_cameras
 from repro.core.gaussians import GaussianScene
-from repro.core.groups import regroup, ungroup
 from repro.core.pipeline import (LuminaConfig, SceneShared, ViewerPrivate,
-                                 ViewerState, batched_prep_features,
-                                 batched_shade_phase, batched_sort_phase,
-                                 copy_pytree, init_fleet, init_scene_shared,
-                                 init_viewer_private, init_viewer_state,
-                                 pytree_nbytes, render_step,
-                                 trim_features_slots)
+                                 ViewerState, batched_shade_phase,
+                                 batched_sort_phase, copy_pytree, init_fleet,
+                                 init_scene_shared, init_viewer_private,
+                                 init_viewer_state, pytree_nbytes,
+                                 render_step)
 from repro.core.tiling import tile_grid
 
 
@@ -112,8 +108,6 @@ class TickTiming(NamedTuple):
     sort_ms: float       # wall-clock of the tick's sort-phase calls
     shade_ms: float      # wall-clock of the tick's shade-phase call
     sorted_slots: int    # speculative sorts executed this tick (incl. admits)
-    kernel_ms: Optional[dict] = None  # per-kernel shade breakdown (profiled
-                                      # ticks on the pallas backend)
 
 
 class _SortGroup(NamedTuple):
@@ -153,7 +147,6 @@ class _InFlight(NamedTuple):
     sort_s: float        # host+device seconds of the sort phase
     n_sched: int
     n_admit: int
-    profile: object      # (prof_shared, prof_priv, cam_b, mask) or None
     tick: int = 0        # global_tick the step ran at (trace span args)
 
 
@@ -163,7 +156,7 @@ class BatchedStepper:
     idle); speculative sorts run once per due (scene, pose-cell) group."""
 
     def __init__(self, scene: GaussianScene, cfg: LuminaConfig,
-                 cam0: Camera, slots: int, profile_every: int = 0,
+                 cam0: Camera, slots: int,
                  viewers_per_scene: int = 1, pool_size: int | None = None,
                  cell_size: float = posecell.CELL_SIZE,
                  cell_ang_bins: int = posecell.ANG_BINS,
@@ -210,7 +203,6 @@ class BatchedStepper:
         # worst-case cohort (admit bursts are chunked to the same width).
         self.cohort = -(-slots // self.window)
         self.global_tick = 0
-        self.profile_every = profile_every
         self.tiles_x, self.tiles_y = tile_grid(cam0.width, cam0.height)
 
         self.shared: SceneShared
@@ -251,9 +243,6 @@ class BatchedStepper:
         self._pending_sort: set[int] = set()   # admitted, not yet sorted
         self.sort_log: list[dict] = []         # per-step sort accounting
         self.last_timing: TickTiming | None = None
-        self.profile_s = 0.0   # cumulative profiling overhead (state copy +
-                               # decomposed stage runs) — callers timing the
-                               # serving loop subtract it for honest fps
 
         self._shade = jax.jit(
             functools.partial(batched_shade_phase, cfg=cfg,
@@ -269,7 +258,6 @@ class BatchedStepper:
                                     donate_argnums=(0, 1))
         self._admit_priv = jax.jit(self._admit_priv_fn, donate_argnums=(0,))
         self._occupancy = jax.jit(rc.occupancy)
-        self._build_kernel_stages()
         # static byte accounting for state_metrics()
         self._pool_entry_bytes = (pytree_nbytes(self.shared.pool)
                                   // (self.num_scenes * self.pool_cap))
@@ -317,18 +305,21 @@ class BatchedStepper:
         lane compaction, the idle lanes of shaded scenes — pass through
         unchanged."""
         lanes = self.viewers_per_scene if lanes is None else lanes
-        sub_shared = jax.tree.map(lambda x: x[scene_idx], shared)
-        sub_priv = jax.tree.map(lambda x: x[slot_idx], priv)
-        sub_cams = jax.tree.map(lambda x: x[slot_idx], cams)
+        with obs_trace.shade_stage('lanes'):
+            sub_shared = jax.tree.map(lambda x: x[scene_idx], shared)
+            sub_priv = jax.tree.map(lambda x: x[slot_idx], priv)
+            sub_cams = jax.tree.map(lambda x: x[slot_idx], cams)
+            sub_sorted = sorted_mask[slot_idx]
         new_sh, new_pr, images, stats = batched_shade_phase(
-            scene, sub_shared, sub_priv, sub_cams, sorted_mask[slot_idx],
-            act_sub, self.cfg, lanes)
-        shared2 = jax.tree.map(
-            lambda full, upd: full.at[scene_tgt].set(upd, mode='drop'),
-            shared, new_sh)
-        priv2 = jax.tree.map(
-            lambda full, upd: full.at[slot_tgt].set(upd, mode='drop'),
-            priv, new_pr)
+            scene, sub_shared, sub_priv, sub_cams, sub_sorted, act_sub,
+            self.cfg, lanes)
+        with obs_trace.shade_stage('lanes'):
+            shared2 = jax.tree.map(
+                lambda full, upd: full.at[scene_tgt].set(upd, mode='drop'),
+                shared, new_sh)
+            priv2 = jax.tree.map(
+                lambda full, upd: full.at[slot_tgt].set(upd, mode='drop'),
+                priv, new_pr)
         return shared2, priv2, images, stats
 
     def _get_lane_jit(self, lanes: int):
@@ -509,99 +500,6 @@ class BatchedStepper:
         """A stashed viewer was evicted: its parked context (and pool
         reference) goes away."""
         self._stash.pop(key, None)
-
-    # -- per-kernel profiling ----------------------------------------------
-
-    def _build_kernel_stages(self) -> None:
-        """Jitted stage functions decomposing the slot-batched pallas shade
-        path for latency attribution (see module docstring).  Each stage is
-        the same function the fused ``batched_shade_phase`` composes, so the
-        split is faithful modulo XLA fusion across stage boundaries."""
-        if self.cfg.backend != 'pallas' or not self.cfg.use_rc:
-            return
-        from repro.kernels import ops
-        cfg = self.cfg
-        tx, ty = self.tiles_x, self.tiles_y
-        chunk = cfg.shade_chunk
-        v = self.viewers_per_scene
-        c = self.num_scenes
-
-        # gauss is an argument (not a closure capture) so a streamed scene
-        # swap never invalidates the profiling stages
-        def prep(gauss, shared, priv, cams):
-            feats_b = batched_prep_features(gauss, shared, priv, cams, cfg, v)
-            feats_b = trim_features_slots(feats_b, tx)
-            return ops.pad_features_slots(feats_b, chunk)
-
-        def probe(caches, st_a, live):
-            ids_g = jax.vmap(
-                lambda r: regroup(r, tx, ty, cfg.group_tiles))(st_a.record)
-            ids_cv = ids_g.reshape(c, v, *ids_g.shape[1:])
-            live_cv = live.reshape(c, v)
-            hit_cv, _, _, _ = jax.vmap(
-                lambda cc, ii, lv: ops.rc_probe_multi(cc, ii, cfg.cache,
-                                                      live=lv)
-            )(caches, ids_cv, live_cv)
-            hit = jax.vmap(
-                lambda h: ungroup(h[..., None], tx, ty,
-                                  cfg.group_tiles)[..., 0]
-            )(hit_cv.reshape(len(live), *hit_cv.shape[2:]))
-            return hit, ids_cv, hit_cv, live_cv
-
-        def resume(feats_b, st_a, miss):
-            t = feats_b.ids.shape[1]
-            return ops.rasterize_resume_compacted_slots(
-                feats_b, tx, st_a, miss, t_img=t, k_record=cfg.k_record,
-                chunk=chunk, bg=cfg.bg)
-
-        def insert(caches, ids_cv, colors, hit_cv, live_cv):
-            raw_g = jax.vmap(
-                lambda cl: regroup(cl, tx, ty, cfg.group_tiles))(colors)
-            raw_cv = raw_g.reshape(c, v, *raw_g.shape[1:])
-            return jax.vmap(
-                lambda cc, ii, rr, dd: rc.insert_all_groups_multi(
-                    cc, ii, rr, dd, cfg.cache)
-            )(caches, ids_cv, raw_cv, ~hit_cv & live_cv[:, :, None, None])
-
-        self._k_prep = jax.jit(prep)
-        self._k_prefix = jax.jit(
-            lambda f, a: ops.rasterize_prefix_slots(
-                f, tx, k_record=cfg.k_record, chunk=chunk, live=a))
-        self._k_lookup = jax.jit(probe)
-        self._k_resume = jax.jit(resume)
-        self._k_insert = jax.jit(insert)
-
-    def _profile_kernels(self, shared: SceneShared, priv: ViewerPrivate,
-                         cams: Camera, active_mask: jax.Array) -> dict:
-        """Time the decomposed shade stages on a pre-shade state copy.
-
-        Each stage lands in the trace as a device-track span nested under
-        one ``shade.profile`` parent — the kernel breakdown Perfetto shows
-        alongside the fused-shade spans it decomposes."""
-        ms = {}
-        stages = []
-
-        def timed(name, f, *args):
-            t0 = time.perf_counter()
-            out = f(*args)
-            jax.block_until_ready(out)
-            t1 = time.perf_counter()
-            ms[name] = (t1 - t0) * 1e3
-            stages.append((name, t0, t1))
-            return out
-
-        feats_b = timed('prep', self._k_prep, self.scene, shared, priv, cams)
-        st_a = timed('prefix', self._k_prefix, feats_b, active_mask)
-        hit, ids_cv, hit_cv, live_cv = timed('lookup', self._k_lookup,
-                                             shared.cache, st_a, active_mask)
-        miss = ~hit & active_mask[:, None, None]
-        colors, _, _ = timed('resume', self._k_resume, feats_b, st_a, miss)
-        timed('insert', self._k_insert, shared.cache, ids_cv, colors,
-              hit_cv, live_cv)
-        self.tracer.complete('shade.profile', stages[0][1], stages[-1][2])
-        for name, t0, t1 in stages:
-            self.tracer.complete(f'kernel.{name}', t0, t1, depth=1)
-        return ms
 
     # -- scheduling ---------------------------------------------------------
 
@@ -1057,7 +955,8 @@ class BatchedStepper:
                                              else self._frames_since_due[i]
                                              + 1)
             if sorting:
-                jax.block_until_ready(self.shared.pool.lists.indices)
+                with self.tracer.span('sort_wait', tick=self.global_tick):
+                    jax.block_until_ready(self.shared.pool.lists.indices)
         else:
             # Baseline mode runs Projection+Sorting for every active lane
             # every frame (inside shade_phase, so its cost lands in
@@ -1080,21 +979,6 @@ class BatchedStepper:
         sorted_mask = jnp.asarray(
             [1.0 if i in sorted_set else 0.0 for i in range(self.slots)],
             jnp.float32)
-
-        do_profile = (self.profile_every > 0
-                      and self.cfg.backend == 'pallas' and self.cfg.use_rc
-                      and self.global_tick % self.profile_every == 0)
-        profile = None
-        if do_profile:
-            # the shade call donates the state — keep a copy to profile
-            t_prof = time.perf_counter()
-            prof_shared = copy_pytree(self.shared)
-            prof_priv = copy_pytree(self.priv)
-            jax.block_until_ready(prof_shared.cache.tags)
-            self.profile_s += time.perf_counter() - t_prof
-            active_mask_full = jnp.asarray(
-                [i in active for i in range(self.slots)], bool)
-            profile = (prof_shared, prof_priv, cam_b, active_mask_full)
 
         v = self.viewers_per_scene
         active_scenes = sorted({int(self._scene_of[i]) for i in active})
@@ -1181,8 +1065,7 @@ class BatchedStepper:
                               'joined': n_joined})
         return _InFlight(cams=cams, images=images, stats=stats, pos=pos,
                          t0=t0, t1=t1, sort_s=sort_s, n_sched=n_sched,
-                         n_admit=n_admit, profile=profile,
-                         tick=self.global_tick - 1)
+                         n_admit=n_admit, tick=self.global_tick - 1)
 
     def step_finish(self, infl) -> dict:
         """Block on a dispatched step's device work and assemble the per-slot
@@ -1196,19 +1079,10 @@ class BatchedStepper:
         self.tracer.complete('shade', infl.t1, t2, tick=infl.tick,
                              slots=len(infl.cams))
 
-        kernel_ms = None
-        if infl.profile is not None:
-            t_prof = time.perf_counter()
-            prof_shared, prof_priv, cam_b, active_mask_full = infl.profile
-            kernel_ms = self._profile_kernels(prof_shared, prof_priv, cam_b,
-                                              active_mask_full)
-            self.profile_s += time.perf_counter() - t_prof
-
         timing = TickTiming(latency_s=t2 - infl.t0,
                             sort_ms=infl.sort_s * 1e3,
                             shade_ms=(t2 - infl.t1) * 1e3,
-                            sorted_slots=infl.n_sched + infl.n_admit,
-                            kernel_ms=kernel_ms)
+                            sorted_slots=infl.n_sched + infl.n_admit)
         self.last_timing = timing
         # every rider of the batch waited for the whole tick
         return {slot: (infl.images[infl.pos[slot]],
@@ -1484,8 +1358,7 @@ class SequentialStepper:
     viewers_per_scene = 1
 
     def __init__(self, scene: GaussianScene, cfg: LuminaConfig,
-                 cam0: Camera, slots: int, profile_every: int = 0):
-        del profile_every   # per-kernel attribution is a batched-engine tool
+                 cam0: Camera, slots: int):
         self.scene = scene
         self.cfg = cfg
         self.slots = slots
@@ -1500,7 +1373,6 @@ class SequentialStepper:
         self.metrics = obs_metrics.Registry()
         self.sort_log: list[dict] = []
         self.last_timing: TickTiming | None = None
-        self.profile_s = 0.0
         self._last_active = 0
         self._pool_entry_bytes = pytree_nbytes(self._fresh.scene_shared.pool)
         self._cache_bytes = pytree_nbytes(self._fresh.scene_shared.cache)

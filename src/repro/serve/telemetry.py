@@ -154,12 +154,6 @@ def tick_rollup(tick_log: list[dict], warmup_ticks: int = 0) -> dict:
     leading ticks (compile + sort-on-admit bursts sit outside the scheduled
     per-tick cohort bound).
 
-    When any tick carries a per-kernel shade breakdown (``kernel_ms``, from
-    the batched stepper's sampled profiling on the pallas backend) the
-    rollup's ``kernel_ms`` maps each kernel stage — prep / prefix / lookup /
-    resume / insert — to its mean milliseconds over the profiled ticks, so
-    the operator sees *where* shade time goes, not just its total.
-
     When ticks carry the stepper's state metrics (scene-shared serving) the
     rollup adds the radiance-cache warm-up view (``mean_occupancy`` /
     ``last_occupancy``) and the state-memory footprint: the peak number of
@@ -185,20 +179,14 @@ def tick_rollup(tick_log: list[dict], warmup_ticks: int = 0) -> dict:
     if not log:
         return {'ticks': 0, 'mean_sorts_per_tick': 0.0,
                 'max_sorts_per_tick': 0, 'mean_sort_ms': 0.0,
-                'mean_shade_ms': 0.0, 'kernel_ms': {}}
+                'mean_shade_ms': 0.0}
     sorts = [t['sorted_slots'] for t in log]
-    profiled = [t['kernel_ms'] for t in log if t.get('kernel_ms')]
-    kernel_ms = {}
-    if profiled:
-        for key in profiled[0]:
-            kernel_ms[key] = float(np.mean([p[key] for p in profiled]))
     roll = {
         'ticks': len(log),
         'mean_sorts_per_tick': float(np.mean(sorts)),
         'max_sorts_per_tick': int(max(sorts)),
         'mean_sort_ms': float(np.mean([t['sort_ms'] for t in log])),
         'mean_shade_ms': float(np.mean([t['shade_ms'] for t in log])),
-        'kernel_ms': kernel_ms,
     }
     # per-frame latency percentiles: each tick's latency, weighted by the
     # frames that rode it (legacy logs without latency_ms just omit these)

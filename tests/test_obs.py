@@ -1,6 +1,6 @@
 """Observability layer (``repro.obs``) + bench regression gating.
 
-Four contracts:
+Five contracts:
 
 * **Tracer/export schema** — spans/instants/explicit device windows record
   with correct nesting depth and export as Chrome trace-event JSON that
@@ -15,17 +15,21 @@ Four contracts:
   raise), label keying, exact percentiles, JSON snapshots; and the
   registry's tick series reproduce ``tick_rollup`` **bit-identically** to
   the ``SessionManager.tick_log`` dict path on a real serving run;
+* **On the profiler's clock** — a live tracer's spans land in a
+  ``jax.profiler`` trace as ``lumina.<span>`` (NULL writes nothing), and
+  the shade program's ops carry every ``shade/<stage>`` scope;
 * **Bench history gating** — ``benchmarks.history.check_payloads`` passes a
   fresh payload equal to its baseline and fails degraded copies
   (fps collapse, p95 blow-up, host_overlap -> 0, chunk-savings sign flip).
 
 Satellites ride along: ``aggregate``'s frame-weighted ``fleet_fps``,
 heterogeneous ``format_table``, and the ``tick_rollup`` edge cases
-(legacy logs, mixed profiling, all-warmup slicing, overlap > 1 warning).
+(legacy logs, all-warmup slicing, overlap > 1 warning).
 """
 import json
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -70,6 +74,88 @@ def test_null_tracer_is_inert():
         NULL.instant('admit')
         NULL.complete('shade', 0.0, 1.0)
     assert NULL.events == [] and not NULL.enabled
+
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` under a ``jax.profiler`` session; the host events of
+    its trace as ``(name, start_ns, end_ns)``, read with ``ProfileData``."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob('*.xplane.pb')
+    pd = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes if plane.name.startswith('/host:')
+            for line in plane.lines for ev in line.events]
+
+
+def test_live_tracer_spans_land_on_the_profiler_clock(tmp_path):
+    """A live tracer's context-manager spans are profiler annotations
+    ``lumina.<name>``, nested as they ran; explicit device windows and
+    instants stay in the tracer alone, and the recorded structure is what
+    it was without a profiler."""
+    tr = Tracer()
+
+    def body():
+        with tr.span('tick', tick=0):
+            with tr.span('plan_tick', tick=0):
+                tr.instant('admit', slot=0, sid=0)
+        tr.complete('shade', 1.0, 2.0, tick=0)
+
+    events = _profiled_host_events(tmp_path, body)
+    lumina = {name: (t0, t1) for name, t0, t1 in events
+              if name.startswith('lumina.')}
+    assert set(lumina) == {'lumina.tick', 'lumina.plan_tick'}
+    (p0, p1), (c0, c1) = lumina['lumina.tick'], lumina['lumina.plan_tick']
+    assert p0 <= c0 <= c1 <= p1
+    assert span_structure(tr.events) == {
+        TRACK_HOST: (('i', 'admit', 0, (('sid', 0), ('slot', 0))),
+                     ('X', 'plan_tick', 1, (('tick', 0),)),
+                     ('X', 'tick', 0, (('tick', 0),))),
+        TRACK_DEVICE: (('X', 'shade', 0, (('tick', 0),)),)}
+
+
+def test_null_tracer_writes_nothing_to_the_profiler(tmp_path):
+    def body():
+        with NULL.span('tick', tick=0):
+            NULL.instant('admit')
+
+    events = _profiled_host_events(tmp_path, body)
+    assert not [name for name, _, _ in events if name.startswith('lumina.')]
+
+
+def test_shade_program_carries_every_stage_scope(small_scene):
+    """The pallas shade program, lowered at test size, names every stage
+    of the shade step in its op metadata: prep, raster, rc_probe and
+    rc_insert in the full-width program, and lanes in the lane-compacted
+    one, which gathers and scatters the live lanes."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core.camera import stack_cameras
+    from repro.obs.trace import SHADE_STAGES
+    cams0 = orbit_trajectory(1, width=64, height_px=64)
+    cfg = LuminaConfig(capacity=128, window=2, backend='pallas')
+    st = BatchedStepper(small_scene, cfg, cams0[0], slots=2,
+                        viewers_per_scene=2)
+    cam_b = stack_cameras(st._slot_cams)
+    flags = jnp.zeros((2,), jnp.float32)
+
+    def stages(fn, *args):
+        text = fn.lower(st.scene, st.shared, st.priv, cam_b, flags,
+                        *args).as_text(debug_info=True)
+        return set(re.findall(r'shade/(\w+)', text))
+
+    full = stages(st._shade, jnp.ones((2,), bool))
+    one = jnp.zeros((1,), jnp.int32)
+    lanes = stages(st._get_lane_jit(1), one, one, one, one,
+                   jnp.ones((1,), bool))
+    assert full == {'prep', 'raster', 'rc_probe', 'rc_insert'}
+    assert lanes == set(SHADE_STAGES)
 
 
 def test_chrome_trace_export_schema_and_tracks(tmp_path):
@@ -152,32 +238,30 @@ def test_registry_snapshot_is_json_serializable():
 
 def test_publish_tick_roundtrip_and_rollup_bit_identity_synthetic():
     """The registry's tick series reconstruct the tick log (including the
-    awkward shapes: ``kernel_ms`` None vs dict, fields present on some
-    ticks only) and the registry rollup equals the dict rollup exactly."""
+    awkward shape of fields present on some ticks only) and the registry
+    rollup equals the dict rollup exactly."""
     log = [
         {'tick': 0, 'frames': 2, 'sorted_slots': 1, 'sort_ms': 0.5,
-         'shade_ms': 3.0, 'kernel_ms': None},
+         'shade_ms': 3.0},
         {'tick': 1, 'frames': 2, 'sorted_slots': 0, 'sort_ms': 0.0,
-         'shade_ms': 2.5, 'kernel_ms': {'prep': 0.1, 'lookup': 0.7},
-         'latency_ms': 3.1, 'host_ms': 0.4, 'overlap_ms': 0.2,
-         'occupancy': np.float32(0.25)},
+         'shade_ms': 2.5, 'latency_ms': 3.1, 'host_ms': 0.4,
+         'overlap_ms': 0.2, 'occupancy': np.float32(0.25)},
         {'tick': 2, 'frames': 1, 'sorted_slots': 2, 'sort_ms': 0.9,
-         'shade_ms': 2.0, 'kernel_ms': {'prep': 0.2, 'lookup': 0.5},
-         'latency_ms': 2.9, 'host_ms': 0.3, 'overlap_ms': 0.1,
-         'occupancy': np.float32(0.5), 'sort_pool_live': 2},
+         'shade_ms': 2.0, 'latency_ms': 2.9, 'host_ms': 0.3,
+         'overlap_ms': 0.1, 'occupancy': np.float32(0.5),
+         'sort_pool_live': 2},
     ]
     reg = Registry()
     for entry in log:
         publish_tick(reg, entry)
     rebuilt = tick_log_from_registry(reg)
     assert [e['tick'] for e in rebuilt] == [0, 1, 2]
-    assert rebuilt[0]['kernel_ms'] is None
-    assert rebuilt[1]['kernel_ms'] == {'prep': 0.1, 'lookup': 0.7}
+    assert 'latency_ms' not in rebuilt[0]
     assert 'sort_pool_live' not in rebuilt[1]
     for want, got in zip(log, rebuilt):
+        assert got.keys() == want.keys()
         for key, val in want.items():
-            if key != 'kernel_ms':
-                assert got[key] is val or got[key] == val
+            assert got[key] is val or got[key] == val
     for warmup in (0, 1):
         assert tick_rollup_from_metrics(reg, warmup_ticks=warmup) == \
             tick_rollup(log, warmup_ticks=warmup)
@@ -439,21 +523,14 @@ def test_tick_rollup_legacy_logs_omit_async_keys():
     roll = tick_rollup([_tick(0), _tick(1)])
     for key in ('p50_frame_ms', 'p95_frame_ms', 'host_ms', 'host_overlap'):
         assert key not in roll
-    assert roll['ticks'] == 2 and roll['kernel_ms'] == {}
-
-
-def test_tick_rollup_mixed_profiled_ticks():
-    roll = tick_rollup([_tick(0, kernel_ms=None),
-                        _tick(1, kernel_ms={'prep': 1.0, 'lookup': 3.0}),
-                        _tick(2, kernel_ms={'prep': 3.0, 'lookup': 5.0})])
-    assert roll['kernel_ms'] == {'prep': 2.0, 'lookup': 4.0}
+    assert roll['ticks'] == 2
 
 
 def test_tick_rollup_warmup_slices_everything():
     roll = tick_rollup([_tick(0), _tick(1)], warmup_ticks=5)
     assert roll == {'ticks': 0, 'mean_sorts_per_tick': 0.0,
                     'max_sorts_per_tick': 0, 'mean_sort_ms': 0.0,
-                    'mean_shade_ms': 0.0, 'kernel_ms': {}}
+                    'mean_shade_ms': 0.0}
 
 
 def test_tick_rollup_overlap_gt_one_warns_unclamped():

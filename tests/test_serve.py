@@ -344,16 +344,23 @@ def test_serve_cli_smoke(capsys):
     assert 'sort_ms' in out and 'sorts/tick' in out
 
 
-def test_serve_cli_pallas_backend_with_profile(capsys):
-    """--backend pallas serves end-to-end and the sampled per-kernel
-    breakdown (prep/prefix/lookup/resume/insert) reaches the rollup."""
+def test_serve_cli_pallas_backend_with_profile(capsys, tmp_path):
+    """--backend pallas serves end-to-end and --profile-dir records a
+    jax.profiler trace holding the shade program's stage scopes (in the
+    HLO of the programs it ran) and the server's lumina.* host spans."""
     from repro.serve import render as serve_render
+    prof = tmp_path / 'profile'
     serve_render.main(['--viewers', '2', '--frames', '4', '--width', '64',
                        '--gaussians', '600', '--capacity', '128',
                        '--stagger', '0', '--backend', 'pallas',
-                       '--profile-every', '2'])
+                       '--profile-dir', str(prof)])
     out = capsys.readouterr().out
     assert 'batched (pallas): 2 sessions' in out
-    assert 'shade kernels (ms/tick, sampled):' in out
-    for stage in ('prep', 'prefix', 'lookup', 'resume', 'insert'):
-        assert stage in out
+    assert f'-- profile: jax.profiler trace -> {prof}' in out
+    (xplane,) = prof.rglob('*.xplane.pb')
+    data = xplane.read_bytes()
+    for stage in ('prep', 'raster', 'rc_probe', 'rc_insert'):
+        assert f'shade/{stage}'.encode() in data
+    for span in ('tick', 'step_dispatch', 'sort_wait', 'observe_tick',
+                 'fetch'):
+        assert f'lumina.{span}'.encode() in data
