@@ -108,6 +108,11 @@ class FrameStats(NamedTuple):
     mean_iterated: jax.Array     # average Gaussians iterated per pixel
     saved_frac: jax.Array        # fraction of integration skipped thanks to RC
     sorted_this_frame: jax.Array # 1.0 if Projection+Sorting ran
+    # the shade run's compacted insert (slot-batched kernel path only,
+    # else None): share of records pending insert, and the branch of
+    # ``rc.insert_compacted`` that ran
+    insert_pending: jax.Array | None = None
+    insert_branch: jax.Array | None = None
 
 
 # Pixel <-> cache-group reshaping lives in repro.core.groups (shared with the
@@ -663,7 +668,10 @@ def _batched_shade_pallas(scene: GaussianScene, shared: SceneShared,
                        .astype(jnp.float32)
                        / jnp.maximum(kst.chunks_bound, 1))
         saved_b = jnp.broadcast_to(saved, (s,))
+        insert = {'insert_pending': jnp.broadcast_to(kst.insert_pending, (s,)),
+                  'insert_branch': jnp.broadcast_to(kst.insert_branch, (s,))}
     else:
+        insert = {}
         with shade_stage('raster'):
             colors, aux, _ = ops.rasterize_full_slots(
                 feats_b, tiles_x, k_record=cfg.k_record,
@@ -676,7 +684,8 @@ def _batched_shade_pallas(scene: GaussianScene, shared: SceneShared,
         images = jax.vmap(
             lambda c: assemble_image(c, tiles_x, tiles_y, cams.width,
                                      cams.height))(colors)
-    stats = jax.vmap(_stats)(aux, hit, saved_b, sorted_flags)
+    stats = jax.vmap(_stats)(aux, hit, saved_b, sorted_flags)._replace(
+        **insert)
     new_shared = dataclasses.replace(shared, cache=caches)
     new_priv = dataclasses.replace(priv, prev_cam=cams,
                                    frame_idx=priv.frame_idx + 1)

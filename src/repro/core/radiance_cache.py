@@ -131,34 +131,37 @@ def lookup(cache: CacheState, group: int | jax.Array, ids: jax.Array,
     return hit, val, sidx, way, new_cache
 
 
-def _insert_round(tags, values, age, clock, sidx, ids, rgb, do_insert):
-    """One insert round: at most one new entry lands per (set, way) slot.
+def _insert_round(tags, values, age, sidx, ids, rgb, pending, pix, new_age):
+    """One insert round: re-probe, then at most one new entry lands per
+    (set, way) slot.  Returns the new tags, values, ages and pending mask.
 
+    The re-probe drops pending records whose tag is already present
+    (landed in an earlier round, or a duplicate of one that did).
     Winners-only scatter: losing lanes get out-of-range indices and are
     dropped (``mode='drop'``), so no stale value can clobber a winner.
     Victim way = first invalid way, else least-recently-used (min age).
-    Conflicts on the same slot: lowest pixel index wins (mirrors the
-    hardware's sequential insert order).
+    Conflicts on the same slot: lowest ``pix`` (the record's pixel in its
+    group) wins, mirroring the hardware's sequential insert order; a
+    winner's age becomes its ``new_age``.
     """
     s, w = age.shape
-    b = ids.shape[0]
     slot_tags = tags[sidx]                                   # [B, W, k]
+    pending = pending & ~jnp.any(_match(slot_tags, ids), axis=-1)
     invalid = jnp.all(slot_tags == INVALID_TAG, axis=-1)     # [B, W]
     slot_age = jnp.where(invalid, jnp.iinfo(jnp.int32).min, age[sidx])
     victim = jnp.argmin(slot_age, axis=-1)                   # [B]
 
     slot = sidx * w + victim                                 # [B]
-    pix = jnp.arange(b, dtype=jnp.int32)
-    winner = jnp.full((s * w,), b, jnp.int32).at[slot].min(
-        jnp.where(do_insert, pix, b))
-    wins = do_insert & (winner[slot] == pix)
+    none = jnp.iinfo(jnp.int32).max
+    winner = jnp.full((s * w,), none, jnp.int32).at[slot].min(
+        jnp.where(pending, pix, none))
+    wins = pending & (winner[slot] == pix)
 
     sidx_eff = jnp.where(wins, sidx, s)                      # out of range -> drop
-    new_age_val = clock + 1 + pix
     tags = tags.at[sidx_eff, victim].set(ids, mode='drop')
     values = values.at[sidx_eff, victim].set(rgb, mode='drop')
-    age = age.at[sidx_eff, victim].set(new_age_val, mode='drop')
-    return tags, values, age, clock + b
+    age = age.at[sidx_eff, victim].set(new_age, mode='drop')
+    return tags, values, age, pending
 
 
 def touch_all_groups(cache: CacheState, ids: jax.Array, hit: jax.Array,
@@ -196,12 +199,13 @@ def insert(cache: CacheState, group: int | jax.Array, ids: jax.Array,
     tags, values, age, clock = (cache.tags[group], cache.values[group],
                                 cache.age[group], cache.clock[group])
     sidx = set_index(ids, cfg)                               # [B]
+    b = ids.shape[0]
+    pix = jnp.arange(b, dtype=jnp.int32)
     pending = do_insert
     for _ in range(max(1, cfg.insert_rounds)):
-        present = jnp.any(_match(tags[sidx], ids), axis=-1)
-        pending = pending & ~present
-        tags, values, age, clock = _insert_round(
-            tags, values, age, clock, sidx, ids, rgb, pending)
+        tags, values, age, pending = _insert_round(
+            tags, values, age, sidx, ids, rgb, pending, pix, clock + 1 + pix)
+        clock = clock + b
 
     return CacheState(cache.tags.at[group].set(tags),
                       cache.values.at[group].set(values),
@@ -297,3 +301,89 @@ def insert_all_groups_multi(cache: CacheState, ids: jax.Array,
     (slot, pixel) order; duplicate tags across viewers land once."""
     return insert_all_groups(cache, slot_major(ids), slot_major(rgb),
                              slot_major(do_insert), cfg)
+
+
+# ---------------------------------------------------------------------------
+# Compacted insert over every scene's cache at once
+# ---------------------------------------------------------------------------
+# A warm cache hits on most records, and only misses insert; yet the rounds
+# of ``insert`` gather, re-probe and scatter every record of the batch (the
+# losers ride along with dropped indices), so a round costs what the whole
+# batch costs.  ``insert_compacted`` gathers the pending records into a
+# short list first and runs the same rounds on it alone, with a
+# ``lax.switch`` over a few static list capacities: the smallest that holds
+# the pending count runs, and a count above them all takes the full-batch
+# ``insert_all_groups_multi``.  The cache evolves exactly as under
+# ``insert_all_groups_multi`` mapped over the caches, bit for bit.
+
+def insert_capacities(n: int) -> tuple[int, ...]:
+    """The record capacities of ``insert_compacted``'s compacted branches
+    for a batch of ``n`` records: 3/4 of it halved five times (3/128 to
+    3/4), each rounded up to a multiple of 128; those not below ``n`` are
+    left out.  The list pads at most 2x past the pending count it holds;
+    past 3/4 the full-batch branch runs, where a compacted list would save
+    little.  Branch ``len(insert_capacities(n))`` is the full batch."""
+    caps = {-(-3 * n // (512 << i)) * 128 for i in range(6)}
+    return tuple(sorted(cap for cap in caps if cap < n))
+
+
+def _rounds_compacted(caches: CacheState, ids: jax.Array, rgb: jax.Array,
+                      pending: jax.Array, cap: int,
+                      cfg: CacheConfig) -> CacheState:
+    """``insert``'s rounds over the first ``cap`` pending records of every
+    cache and group (``pending`` holds at most ``cap``), on views of the
+    caches with one axis of sets: set ``(c*G + g)*S + s``."""
+    c, v, g, b = pending.shape
+    n, vb = pending.size, v * b
+    s, w, k = cfg.n_sets, cfg.n_ways, cfg.k
+    # the pending records' indices in order, padded with n: a sort of one
+    # key per record (no scatter over the batch)
+    key = jnp.where(pending.reshape(n), jnp.arange(n, dtype=jnp.int32), n)
+    idx = jax.lax.sort(key)[:cap]
+    pend = idx < n
+    idx = jnp.minimum(idx, n - 1)
+    cg = idx // (v * g * b) * g + idx // b % g    # the record's (cache, group)
+    pix = idx // (g * b) % v * b + idx % b        # its slot-major pixel there
+    rid = ids.reshape(n, k)[idx]
+    rrgb = rgb.reshape(n, 3)[idx]
+    gset = cg * s + set_index(rid, cfg)
+    clock = caches.clock.reshape(c * g)[cg]
+
+    tags = caches.tags.reshape(c * g * s, w, k)
+    values = caches.values.reshape(c * g * s, w, 3)
+    age = caches.age.reshape(c * g * s, w)
+    rounds = max(1, cfg.insert_rounds)
+    for r in range(rounds):
+        tags, values, age, pend = _insert_round(
+            tags, values, age, gset, rid, rrgb, pend, pix,
+            clock + r * vb + 1 + pix)
+    return CacheState(tags.reshape(caches.tags.shape),
+                      values.reshape(caches.values.shape),
+                      age.reshape(caches.age.shape),
+                      caches.clock + rounds * vb)
+
+
+def insert_compacted(caches: CacheState, ids: jax.Array, rgb: jax.Array,
+                     do_insert: jax.Array, cfg: CacheConfig):
+    """``insert_all_groups_multi`` mapped over C caches, on the pending
+    records alone: caches leaves [C, G, ...], ids [C, V, G, B, k], rgb
+    [C, V, G, B, 3], do_insert [C, V, G, B].
+
+    Returns ``(new caches, pending share, branch)``: the share of records
+    that were pending (``do_insert`` with finite rgb) and the index of the
+    branch that ran, ``len(insert_capacities(C*V*G*B))`` for the full
+    batch.  Call it outside any ``vmap``: under one the switch becomes a
+    select that runs every branch."""
+    pending = do_insert & jnp.isfinite(rgb).all(axis=-1)
+    caps = insert_capacities(pending.size)
+    count = jnp.sum(pending, dtype=jnp.int32)
+    branch = jnp.sum(count > jnp.asarray(caps, jnp.int32), dtype=jnp.int32)
+
+    def full(cc, ii, rr, pp):
+        return jax.vmap(lambda *a: insert_all_groups_multi(*a, cfg))(
+            cc, ii, rr, pp)
+
+    branches = [lambda cc, ii, rr, pp, cap=cap: _rounds_compacted(
+        cc, ii, rr, pp, cap, cfg) for cap in caps] + [full]
+    new = jax.lax.switch(branch, branches, caches, ids, rgb, pending)
+    return new, count / pending.size, branch

@@ -372,6 +372,10 @@ class RCStats(NamedTuple):
     chunks_resume: jax.Array   # chunk iterations, phase B
     chunks_bound: jax.Array    # count-capped full-pass chunk total (scalar)
     hit: jax.Array             # [T, P] bool per-pixel cache-hit mask
+    # the compacted insert (``rc.insert_compacted``; slot-batched path
+    # only): share of records pending insert, and the branch that ran
+    insert_pending: jax.Array | None = None
+    insert_branch: jax.Array | None = None
 
 
 def rasterize_with_rc(feats: TileFeatures, tiles_x: int, tiles_y: int,
@@ -533,8 +537,10 @@ def rasterize_with_rc_slots(feats_b: TileFeatures, tiles_x: int,
                             interpret: bool | None = None):
     """Slot-batched cached rasterization: phase A in one slot-batched
     kernel, scene-major shared-cache probe, cross-slot miss-compacted
-    resume, scene-major insert, each inside its shade stage's named scope
-    (``repro.obs.trace.shade_stage``: raster, rc_probe, rc_insert).
+    resume, and an insert of the pending records alone over every scene's
+    cache at once (``rc.insert_compacted``), each inside its shade stage's
+    named scope (``repro.obs.trace.shade_stage``: raster, rc_probe,
+    rc_insert).
     ``caches`` leaves carry a leading [C] axis with ``C = S //
     viewers_per_scene`` (slot ``i`` probes scene ``i // V``'s cache; slots
     of one scene share it, conflicts resolving in deterministic (slot,
@@ -597,10 +603,9 @@ def rasterize_with_rc_slots(feats_b: TileFeatures, tiles_x: int,
         raw_g = jax.vmap(
             lambda cl: regroup(cl, tiles_x, tiles_y, group_tiles))(colors)
         raw_cv = raw_g.reshape(c, v, *raw_g.shape[1:])
-        caches = jax.vmap(
-            lambda cc, ii, rr, dd: rc.insert_all_groups_multi(cc, ii, rr, dd,
-                                                              cfg)
-        )(caches, ids_cv, raw_cv, ~hit_cv & live_cv[:, :, None, None])
+        # the rounds run on the pending records alone (most records hit)
+        caches, insert_pending, insert_branch = rc.insert_compacted(
+            caches, ids_cv, raw_cv, ~hit_cv & live_cv[:, :, None, None], cfg)
 
     with shade_stage('raster'):
         ncap = chunk_caps(feats_b.ids.reshape(s * t, -1), chunk)
@@ -614,5 +619,7 @@ def rasterize_with_rc_slots(feats_b: TileFeatures, tiles_x: int,
             chunks_resume=jnp.sum(chunks_b),          # fleet (cross-slot)
             chunks_bound=jnp.sum(ncap),               # fleet
             hit=hit,                                  # [S, T, P]
+            insert_pending=insert_pending,
+            insert_branch=insert_branch,
         )
     return final, caches, aux, stats

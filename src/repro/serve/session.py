@@ -640,6 +640,12 @@ class SessionManager:
                                   + (host.overlap_ms if host else 0.0),
                 }
                 self._carry_host_ms = self._carry_overlap_ms = 0.0
+                # one shade run a tick: any frame's stats carry its insert
+                # counter (device scalars, not synced here, as occupancy)
+                _image, stats, _t = next(iter(outputs.values()))
+                if stats.insert_branch is not None:
+                    entry['insert_pending'] = stats.insert_pending
+                    entry['insert_branch'] = stats.insert_branch
                 metrics = getattr(self.stepper, 'state_metrics', None)
                 if metrics is not None:
                     entry.update(metrics())

@@ -268,3 +268,61 @@ def test_pallas_saved_frac_is_measured_not_modeled(small_scene, cams64):
         hits.append(float(st.hit_rate))
     assert hits[-1] > 0.5, hits
     assert saved[-1] > saved[0] + 0.2, saved
+
+
+def test_compacted_insert_keeps_reference_cache_across_frames(small_scene):
+    """The slot-batched kernel shade inserts through
+    ``rc.insert_compacted``.  From a cold shared cache (two viewers of one
+    scene) over several frames, its images stay within the kernel
+    tolerance of the reference backend and its hit rates and cache tags,
+    LRU ages and clock equal the reference's bit for bit; its counter
+    reports the full-batch branch on the cold frame and a compacted branch
+    once the cache is warm."""
+    s, v, frames = 2, 2, 4
+    cfg_p = LuminaConfig(capacity=128, window=frames, backend='pallas')
+    cfg_r = dataclasses.replace(cfg_p, backend='reference')
+    trajs = [orbit_trajectory(frames, width=64, height_px=64,
+                              start_deg=3.0 * i) for i in range(s)]
+    from repro.core.pipeline import batched_sort_phase
+    shared, priv = init_fleet(small_scene, cfg_p, trajs[0][0], slots=s,
+                              viewers_per_scene=v)
+    cams = stack_cameras([t[0] for t in trajs])
+    entries = jax.jit(functools.partial(batched_sort_phase, cfg=cfg_p))(
+        small_scene, priv, cams)
+    shared = dataclasses.replace(shared, pool=jax.tree.map(
+        lambda p, e: p.at[0, 0].set(e[0]), shared.pool, entries))
+    states = {'pallas': (shared, priv), 'reference': (shared, priv)}
+    shades = {name: jax.jit(functools.partial(
+        batched_shade_phase, cfg=cfg, viewers_per_scene=v))
+        for name, cfg in (('pallas', cfg_p), ('reference', cfg_r))}
+    sm = jnp.zeros((s,), jnp.float32)
+    am = jnp.ones((s,), bool)
+    n_records = s * 4096                     # one 64x64-pixel cache group
+    full = len(rc.insert_capacities(n_records))
+    branches = []
+    for f in range(frames):
+        cams = stack_cameras([t[f] for t in trajs])
+        out = {}
+        for name, shade in shades.items():
+            sh, pr, images, stats = shade(small_scene, *states[name], cams,
+                                          sm, am)
+            states[name] = (sh, pr)
+            out[name] = (images, stats)
+        (img_p, st_p), (img_r, st_r) = out['pallas'], out['reference']
+        _ulp_close(img_p, img_r, msg=f'frame {f}')
+        np.testing.assert_array_equal(np.asarray(st_p.hit_rate),
+                                      np.asarray(st_r.hit_rate))
+        cache_p = states['pallas'][0].cache
+        cache_r = states['reference'][0].cache
+        for field in ('tags', 'age', 'clock'):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(cache_p, field)),
+                np.asarray(getattr(cache_r, field)),
+                err_msg=f'{field}, frame {f}')
+        _ulp_close(cache_p.values, cache_r.values, msg=f'values, frame {f}')
+        assert st_r.insert_branch is None
+        branches.append(int(st_p.insert_branch[0]))
+        assert float(st_p.insert_pending[0]) == pytest.approx(
+            1.0 - float(jnp.mean(st_p.hit_rate)))
+    assert branches[0] == full, branches
+    assert all(b < full for b in branches[1:]), branches

@@ -117,3 +117,86 @@ def test_bitconcat_index_mode():
     ids = jnp.asarray([[8, 16, 24], [8, 16, 25]], jnp.int32)
     idx = np.asarray(rc.set_index(ids, cfg))
     assert ((idx >= 0) & (idx < 64)).all()
+
+
+# ---------------------------------------------------------------------------
+# insert_compacted: the full-batch rounds on the pending records alone
+# ---------------------------------------------------------------------------
+
+_CCFG = rc.CacheConfig(n_sets=4, n_ways=2, k=3)   # few slots: many conflicts
+_N = 8192                                          # records, every (C, V)
+_full_insert = jax.jit(lambda cc, ii, rr, dd: jax.vmap(
+    lambda *a: rc.insert_all_groups_multi(*a, _CCFG))(cc, ii, rr, dd))
+_compacted_insert = jax.jit(
+    lambda *a: rc.insert_compacted(*a, _CCFG))
+
+
+def _warm_caches(c, g, seed):
+    """Caches that hold entries, touched ages and a running clock."""
+    caches = jax.vmap(lambda _: rc.init_cache(g, _CCFG))(jnp.arange(c))
+    key = jax.random.PRNGKey(seed)
+    for i in range(2):
+        ids = jax.random.randint(jax.random.fold_in(key, i),
+                                 (c, 1, g, 64, 3), 0, 8)
+        rgb = jax.random.uniform(jax.random.fold_in(key, 10 + i),
+                                 (c, 1, g, 64, 3))
+        caches = _full_insert(caches, ids, rgb, jnp.ones(ids.shape[:4], bool))
+    _, _, _, _, caches = jax.vmap(
+        lambda cc, ii: rc.lookup_all_groups_multi(cc, ii, _CCFG))(
+            caches, ids[:, :, :, ::2])
+    return caches
+
+
+@pytest.mark.parametrize('edge', ['lo', 'hi'])
+@pytest.mark.parametrize('branch', range(7))
+@pytest.mark.parametrize('c,v', [(1, 1), (1, 2), (2, 1)])
+def test_compacted_insert_matches_full_batch(c, v, branch, edge):
+    """``insert_compacted`` evolves every cache exactly as the full-batch
+    ``insert_all_groups_multi``: tags, values, LRU ages and clock bit for
+    bit, whichever capacity branch the pending count lands in (each
+    branch's lowest and highest count, and overflow into the full batch),
+    over 4 sets x 2 ways so all four rounds resolve slot conflicts, with
+    duplicate records across viewers, non-finite colours that must not
+    insert and, at V == 2, a dead viewer."""
+    g = 2
+    b = _N // (c * v * g)
+    caps = rc.insert_capacities(_N)
+    assert len(caps) == 6
+    bounds = [0, *caps, _N]
+    count = bounds[branch] + 1 if edge == 'lo' else bounds[branch + 1]
+    seed = 100 * c + 10 * v + branch
+    key = jax.random.PRNGKey(seed)
+    shape = (c, v, g, b)
+    ids = jax.random.randint(jax.random.fold_in(key, 0), shape + (3,), 0, 12)
+    if v == 2:   # viewer 1 repeats half of viewer 0's records
+        ids = ids.at[:, 1, :, ::2].set(ids[:, 0, :, ::2])
+    rgb = jax.random.uniform(jax.random.fold_in(key, 1), shape + (3,))
+    order = np.asarray(jax.random.permutation(jax.random.fold_in(key, 2),
+                                              _N))
+    flat = np.zeros(_N, bool)
+    flat[order[:count]] = True
+    live = np.ones(shape, bool)
+    if v == 2 and count < _N // 2:   # viewer 1 dead: its records never insert
+        live[:, 1] = False
+        flat = np.zeros(_N, bool)
+        flat[np.flatnonzero(live.reshape(-1))[:count]] = True
+    do = flat.reshape(shape)
+    # non-finite colours on records that are not pending: asked to insert,
+    # yet gated out by the finite check
+    bad = np.flatnonzero(~flat & live.reshape(-1))[:7]
+    rgb = rgb.reshape(_N, 3).at[bad, 1].set(
+        jnp.asarray([np.nan, np.inf, -np.inf, np.nan, np.inf, np.nan,
+                     -np.inf][:len(bad)], jnp.float32)).reshape(shape + (3,))
+    do = jnp.asarray(do.reshape(-1).copy()).at[bad].set(True).reshape(shape)
+    caches = _warm_caches(c, g, seed)
+
+    want = _full_insert(caches, ids, rgb, do)
+    got, share, ran = _compacted_insert(caches, ids, rgb, do)
+    assert int(ran) == branch
+    assert float(share) == count / _N
+    for field in rc.CacheState._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                      np.asarray(getattr(want, field)),
+                                      err_msg=field)
+    # the batch did insert: a parity over an unchanged cache proves nothing
+    assert not np.array_equal(np.asarray(want.tags), np.asarray(caches.tags))
